@@ -18,6 +18,8 @@ import sys
 import traceback
 from pathlib import Path
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from .common import Csv
 
 _SUITES = ["fig3", "fig8", "table1", "fig9", "fig10", "fig11", "fig12",
@@ -42,6 +44,7 @@ def main() -> None:
                     help="smoke mode: shrink benchmark shapes (sets "
                          "NXFP_BENCH_QUICK=1 for suites that honor it)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.quick:
         os.environ["NXFP_BENCH_QUICK"] = "1"
     only = args.only.split(",") if args.only else _SUITES

@@ -848,11 +848,42 @@ print("SHARDED_BENCH_OK")
 """
 
 
+def _run_host_devices(script: str, n_devices: int, tag: str,
+                      *argv: str) -> str:
+    """Run a multi-device bench script in a child on ``n_devices`` forced
+    host CPU devices; returns its stdout (which must print ``tag``).
+
+    The child is pinned to the CPU: a chip belongs to one process, and
+    this one already holds JAX.  On a TPU backend the host-device
+    simulation measures nothing the chip would, so it is refused — the
+    sharded engine runs in-process there (``chip_smoke.py --chips 4``).
+    """
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{tag}: this bench simulates {n_devices} devices on the host "
+            f"CPU in a child process; on a TPU run the sharded engine "
+            f"in-process on the real devices (chip_smoke.py --chips 4)")
+    # APPEND the forced-device flag: the child rows must run under the
+    # same compiler flags as every other row in the summary
+    flags = (os.environ.get("XLA_FLAGS", "")
+             + f" --xla_force_host_platform_device_count={n_devices}").strip()
+    env = {**os.environ, "XLA_FLAGS": flags, "PYTHONPATH": "src",
+           "JAX_PLATFORMS": "cpu"}
+    out = subprocess.run(
+        [sys.executable, "-c", script, *argv],
+        env=env, capture_output=True, text=True, timeout=1200,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    if tag not in out.stdout:
+        raise AssertionError(f"{tag}: bench subprocess failed:\n"
+                             f"{out.stdout}\n{out.stderr}")
+    return out.stdout
+
+
 def run_sharded(csv: Csv):
     """Slot-sharded vs unsharded continuous serving, 1/2/4 shards.
 
-    Runs in a subprocess with 4 forced host devices (this process must
-    keep one device).  The script re-serves the SAME Poisson mixed-length
+    Runs in a subprocess with 4 forced host CPU devices (this process
+    must keep one device).  The script re-serves the SAME Poisson mixed-length
     workload at each shard count and raises if any sharded token stream
     diverges from the unsharded engine — the sharded bitwise oracle rides
     the bench exactly like the chunked-prefill one does.
@@ -865,20 +896,9 @@ def run_sharded(csv: Csv):
     """
     quick = _quick()
     n_slots, chunk, p_chunk = 4, (8 if quick else 16), 8
-    # APPEND the forced-device flag: the subprocess rows must run under
-    # the same compiler flags as every other row in the summary
-    flags = (os.environ.get("XLA_FLAGS", "")
-             + " --xla_force_host_platform_device_count=4").strip()
-    env = {**os.environ, "XLA_FLAGS": flags, "PYTHONPATH": "src"}
-    out = subprocess.run(
-        [sys.executable, "-c", _SHARDED_SCRIPT,
-         json.dumps([quick, n_slots, chunk, p_chunk])],
-        env=env, capture_output=True, text=True, timeout=1200,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    if "SHARDED_BENCH_OK" not in out.stdout:
-        raise AssertionError(f"sharded bench subprocess failed:\n"
-                             f"{out.stdout}\n{out.stderr}")
-    for line in out.stdout.splitlines():
+    out = _run_host_devices(_SHARDED_SCRIPT, 4, "SHARDED_BENCH_OK",
+                            json.dumps([quick, n_slots, chunk, p_chunk]))
+    for line in out.splitlines():
         if not line.startswith("ROW "):
             continue
         row = json.loads(line[4:])
@@ -992,17 +1012,8 @@ def run_drain(csv: Csv):
     prices the migration pause against the healthy run; same CPU caveat
     as ``run_sharded`` (overheads are real, scaling is not).
     """
-    flags = (os.environ.get("XLA_FLAGS", "")
-             + " --xla_force_host_platform_device_count=2").strip()
-    env = {**os.environ, "XLA_FLAGS": flags, "PYTHONPATH": "src"}
-    out = subprocess.run(
-        [sys.executable, "-c", _DRAIN_SCRIPT],
-        env=env, capture_output=True, text=True, timeout=1200,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    if "DRAIN_BENCH_OK" not in out.stdout:
-        raise AssertionError(f"drain bench subprocess failed:\n"
-                             f"{out.stdout}\n{out.stderr}")
-    for line in out.stdout.splitlines():
+    out = _run_host_devices(_DRAIN_SCRIPT, 2, "DRAIN_BENCH_OK")
+    for line in out.splitlines():
         if not line.startswith("ROW "):
             continue
         row = json.loads(line[4:])

@@ -25,30 +25,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-__all__ = ["pack_codes", "unpack_codes", "bytes_per_block", "pack_tile",
-           "byte_fold"]
+__all__ = ["pack_codes", "unpack_codes", "bytes_per_block", "byte_fold"]
 
 
 def bytes_per_block(block_size: int, bits: int) -> int:
     total = block_size * bits
     assert total % 8 == 0, (block_size, bits)
     return total // 8
-
-
-def pack_tile(bits: int, block_size: int = 32):
-    """Kernel pack-tile granularity (DESIGN.md §2.4): (codes, bytes).
-
-    In-byte widths (4/8-bit: every code lives inside one byte) tile per
-    quantization block. Byte-straddling widths (5/6-bit) tile per *two*
-    adjacent blocks — 64 codes in 40/48 bytes at block_size 32 — the unit
-    the Pallas kernels consume. Because ``block_size * bits`` is a whole
-    number of bytes, the little-endian layout of a two-block tile is
-    exactly the concatenation of its blocks' layouts: the tile is purely a
-    kernel granularity choice, and packed bytes stay bit-identical to
-    ``pack_codes`` / ``pack_codes_scatter`` per single block.
-    """
-    blocks = 1 if bits in (4, 8) else 2
-    return blocks * block_size, blocks * bytes_per_block(block_size, bits)
 
 
 @lru_cache(maxsize=None)

@@ -153,18 +153,21 @@ def direct_cast_tree(params, policy: QuantPolicy, quantize_fn=None):
     default is the reference-oracle ``QTensor.quantize``. The serving
     engine passes ``repro.kernels.ops.quantize_qtensor`` so load-time
     weight casts ride the fused encode+pack kernel (core cannot import
-    kernels itself — that would be a circular import).
+    kernels itself — that would be a circular import).  Leaves that are
+    already ``QTensor`` stay as they are, so casting a cast tree is the
+    identity.
     """
     qfn = quantize_fn or (
         lambda leaf, fmt, axis: QTensor.quantize(leaf, fmt, axis=axis))
 
     def cast(path, leaf):
         p = _path_str(path)
-        if policy.matches(p, leaf):
+        if not isinstance(leaf, QTensor) and policy.matches(p, leaf):
             return qfn(leaf, policy.weight_fmt, policy.axis)
         return leaf
 
-    return jax.tree_util.tree_map_with_path(cast, params)
+    return jax.tree_util.tree_map_with_path(
+        cast, params, is_leaf=lambda l: isinstance(l, QTensor))
 
 
 def dense_like(qparams):

@@ -6,21 +6,31 @@ decode sign/microexponent/mantissa fields with vector integer ops and build
 powers of two by assembling float32 exponent bits directly (exact, no
 transcendentals).
 
+Kernel tiles use the *plane* layout (DESIGN.md §2.4): k-bit codes are
+grouped ``P`` codes to ``Bg`` bytes (``code_group``), byte plane ``q``
+holds byte ``q`` of every group and code plane ``p`` holds code ``p`` of
+every group, so a tile unpacks with whole-vector shifts and masks — no
+lane interleave, no gather.  A group never straddles a quantization
+block, so the per-block scale expands to a plane's rows as a plain
+sublane repeat (``expand_rows``).
+
 All functions are pure jnp and usable both inside ``pl.pallas_call`` bodies
 and in plain XLA code.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.formats import BlockFormat, ELEMENT_FORMATS
 from repro.core.levels import level_table
-from repro.core.pack import pack_tile
 from repro.core.quantize import pow2i  # canonical definition (re-export)
 
-__all__ = ["pow2i", "decode_elem", "decode_scale", "decode_block_values",
-           "decode_block_values_ex", "byte_routes", "unpack_codes_pallas"]
+__all__ = ["pow2i", "decode_elem", "decode_values", "decode_block_values",
+           "byte_routes", "code_group", "unpack_planes", "expand_rows",
+           "decode_planes"]
 
 
 def decode_elem(codes, elem_name: str, cr: bool):
@@ -54,138 +64,132 @@ def decode_elem(codes, elem_name: str, cr: bool):
     return val
 
 
-def decode_scale(meta):
-    """meta int32 (packed uint16 semantics) -> (scale f32, fmt_bit int32)."""
-    m = meta.astype(jnp.int32)
-    e_shared = (m & 0xFF) - 128
-    nano = (m >> 8) & 0x3
-    fmt_bit = (m >> 10) & 0x1
-    scale = (1.0 + nano.astype(jnp.float32) * 0.25) * pow2i(e_shared)
-    return scale, fmt_bit
+def _scale(m, shift: int):
+    """(1 + nano/4) * 2**E of the scale field at ``shift`` in meta ``m``."""
+    e = ((m >> shift) & 0xFF) - 128
+    nano = (m >> (shift + 8)) & 0x3
+    return (1.0 + nano.astype(jnp.float32) * 0.25) * pow2i(e)
 
 
-def decode_block_values(codes, meta, fmt: BlockFormat):
-    """codes (..., nb, B) int-like, meta (..., nb) -> f32 values (original units).
+def decode_values(codes, meta, pos, fmt: BlockFormat):
+    """Per-element decode: codes, meta and pos all share one shape.
 
-    Mirrors ``repro.core.quantize.dequantize_blocks`` exactly (bit-identical:
-    level values and scales are exact in f32 in both paths).
-    """
-    if fmt.asym or fmt.ox:
-        return decode_block_values_ex(codes, meta, fmt)
-    scale, fmt_bit = decode_scale(meta)
-    vals = None
-    for fb, elem in fmt.elem_formats:
-        v = decode_elem(codes, elem.name, fmt.cr)
-        vals = v if vals is None else jnp.where(
-            (fmt_bit == fb)[..., None], v, vals)
-    return vals * scale[..., None]
+    ``meta`` is each element's block meta word (int32; uint32 semantics for
+    asymmetric formats, whose 26 meta bits fit losslessly) and ``pos`` its
+    index inside the block (read only by ``ox`` formats).  The caller
+    broadcasts the per-block meta to elements — ``decode_block_values`` in
+    XLA, ``expand_rows`` inside the kernels — so no op here reshapes.
 
-
-def decode_block_values_ex(codes, meta, fmt: BlockFormat):
-    """Arithmetic decode of the activation-side formats (``asym`` / ``ox``).
-
-    Mirrors ``repro.core.quantize._dequantize_blocks_ex`` bit-exactly, with
-    the element LUT replaced by ``decode_elem`` and ``ldexp`` by the
-    exponent-bit ``pow2i`` assembly — every op is Pallas-legal, so the qq
-    matmul kernel's dual decode tile runs exactly this function. ``meta``
-    carries uint32 semantics for asymmetric formats (callers pass int32;
-    26 meta bits fit losslessly).
+    Mirrors ``repro.core.quantize.dequantize_blocks`` bit-exactly: level
+    values and scales are exact in f32 on both paths.
     """
     m = meta.astype(jnp.int32)
-    e_p = (m & 0xFF) - 128
-    scale_p = (1.0 + ((m >> 8) & 0x3).astype(jnp.float32) * 0.25) * pow2i(e_p)
-    fmt_bit = (m >> 10) & 0x1
     c = codes.astype(jnp.int32)
+    fmt_bit = (m >> 10) & 0x1
     vals = None
     for fb, elem in fmt.elem_formats:
         v = decode_elem(c, elem.name, fmt.cr)
-        vals = v if vals is None else jnp.where(
-            (fmt_bit == fb)[..., None], v, vals)
+        vals = v if vals is None else jnp.where(fmt_bit == fb, v, vals)
+    scale_p = _scale(m, 0)
     if fmt.asym:
-        e_n = ((m >> 16) & 0xFF) - 128
-        scale_n = (1.0 + ((m >> 24) & 0x3).astype(jnp.float32) * 0.25) \
-            * pow2i(e_n)
-        out = vals * jnp.where(vals < 0, scale_n[..., None],
-                               scale_p[..., None])
+        out = vals * jnp.where(vals < 0, _scale(m, 16), scale_p)
     else:
-        e_n = e_p
-        out = vals * scale_p[..., None]
+        out = vals * scale_p
     if fmt.ox:
+        # the block max's slot holds sign | bits-1 mantissa bits of the max
+        # itself, decoded absolutely off its sign's shared exponent
         elem = fmt.elem_formats[0][1]
         emax = level_table(elem.name, False, fmt.recycle).emax
-        bits = fmt.bits
-        mb = bits - 1
+        mb = fmt.bits - 1
         sign = (c >> mb) & 1
         mag = c & ((1 << mb) - 1)
-        if fmt.asym:
-            e_used = jnp.where(sign == 1, e_n[..., None], e_p[..., None])
-        else:
-            e_used = jnp.broadcast_to(e_p[..., None], sign.shape)
+        e_p = (m & 0xFF) - 128
+        e_used = jnp.where(sign == 1, ((m >> 16) & 0xFF) - 128, e_p) \
+            if fmt.asym else e_p
         vox = (1.0 + mag.astype(jnp.float32) * (0.5 ** mb)) \
             * pow2i(e_used + emax)
         vox = jnp.where(sign == 1, -vox, vox)
-        iota = jax.lax.broadcasted_iota(jnp.int32, c.shape, c.ndim - 1)
-        idx = (m >> 11) & 0x1F
-        sub = (iota == idx[..., None]) & ((m & 0xFF) != 0)[..., None]
+        sub = (pos == ((m >> 11) & 0x1F)) & ((m & 0xFF) != 0)
         out = jnp.where(sub, vox, out)
     return out
 
 
-def byte_routes(n_codes: int, bits: int, n_bytes: int, code_axis: int):
-    """Iota-built 0/1 lo/spill byte-routing constants (core.pack layout).
+def decode_block_values(codes, meta, fmt: BlockFormat):
+    """codes (..., nb, B) int-like, meta (..., nb) -> f32 values (original
+    units) — the XLA-side entry of ``decode_values``."""
+    c = codes.astype(jnp.int32)
+    m = jnp.broadcast_to(meta.astype(jnp.int32)[..., None], c.shape)
+    pos = jax.lax.broadcasted_iota(jnp.int32, c.shape, c.ndim - 1)
+    return decode_values(c, m, pos, fmt)
+
+
+def byte_routes(n_codes: int, bits: int, n_bytes: int):
+    """Iota-built (n_codes, n_bytes) 0/1 lo/spill byte-routing constants
+    (the ``core.pack`` layout) for the in-kernel pack.
 
     (Pallas kernels cannot capture array constants, so the routes are
-    rebuilt from ``broadcasted_iota`` comparisons — XLA folds them.)
-    ``code_axis=0`` -> (n_codes, n_bytes), the pack orientation;
-    ``code_axis=1`` -> (n_bytes, n_codes), the unpack orientation — each
-    built directly so Mosaic never sees a transpose op. The lo route
-    selects code i's low byte, the spill route its high byte, clamped to
-    the last byte when there is no spill (the clamped byte's contribution
-    is zero on the pack side and masked off on the unpack side, as in
-    ``core.pack``).
+    rebuilt from ``broadcasted_iota`` comparisons — XLA folds them.)  The
+    lo route selects code i's low byte, the spill route its high byte,
+    clamped to the last byte when there is no spill (the clamped byte's
+    contribution is zero, as in ``core.pack``).
     """
-    shape = (n_codes, n_bytes) if code_axis == 0 else (n_bytes, n_codes)
-    i = jax.lax.broadcasted_iota(jnp.int32, shape, code_axis)
-    b = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - code_axis)
+    shape = (n_codes, n_bytes)
+    i = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    b = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     lo = (i * bits) // 8
     hi = jnp.minimum(lo + 1, n_bytes - 1)
     return (b == lo).astype(jnp.float32), (b == hi).astype(jnp.float32)
 
 
-def unpack_codes_pallas(packed, bits: int):
-    """(..., nb, bpb) uint8 -> (..., nb, B) int32 codes. k in {4, 5, 6, 8}.
+def code_group(bits: int, p_min: int = 1):
+    """(P codes, Bg bytes): the smallest whole-byte run of k-bit codes
+    holding a multiple of ``p_min`` codes.
 
-    4/8-bit codes never straddle a byte, so the unpack is pure vector
-    shifts. 5/6-bit codes do straddle: the unpack runs over the two-block
-    (64-code, 40/48-byte) pack tile (``core.pack.pack_tile``) as a pair of
-    tiny constant 0/1 byte-selection matmuls — the transposed shift-or
-    routing of ``core.pack.unpack_codes`` — plus vector shift/mask. Still
-    no gathers, so it is legal and fast inside Mosaic. Callers must pass
-    an even number of blocks for 5/6-bit (ops.py gates eligibility).
+    4-bit (2, 1), 8-bit (1, 1), 6-bit (4, 3), 5-bit (8, 5).  ``P`` divides
+    every block size in use (32, 16), so groups never straddle a block.
+    ``p_min`` lets two operands of one GEMM split K into the same planes.
     """
-    b = packed.astype(jnp.int32)
-    if bits == 8:
-        return b
-    if bits == 4:
-        lo = b & 0xF
-        hi = (b >> 4) & 0xF
-        out = jnp.stack([lo, hi], axis=-1)
-        return out.reshape(*packed.shape[:-1], packed.shape[-1] * 2)
-    if bits in (5, 6):
-        nb, bpb = packed.shape[-2], packed.shape[-1]
-        assert nb % 2 == 0, (
-            f"{bits}-bit unpack consumes two-block pack tiles; got {nb} blocks")
-        block = bpb * 8 // bits
-        n_codes, n_bytes = pack_tile(bits, block)
-        rows = packed.astype(jnp.float32).reshape(-1, n_bytes)
-        lo_sel, hi_sel = byte_routes(n_codes, bits, n_bytes, code_axis=1)
-        # routes are one-hot per code: the f32 dots are exact byte selects
-        lo_b = jax.lax.dot(rows, lo_sel,
-                           preferred_element_type=jnp.float32).astype(jnp.int32)
-        hi_b = jax.lax.dot(rows, hi_sel,
-                           preferred_element_type=jnp.float32).astype(jnp.int32)
-        word = lo_b | (hi_b << 8)
-        off = (jax.lax.broadcasted_iota(jnp.int32, word.shape, 1) * bits) % 8
-        codes = (word >> off) & ((1 << bits) - 1)
-        return codes.reshape(*packed.shape[:-2], nb, block)
-    raise NotImplementedError(f"pallas unpack supports 4/5/6/8-bit, got {bits}")
+    p = math.lcm(8 // math.gcd(8, bits), p_min)
+    return p, p * bits // 8
+
+
+def unpack_planes(byte_planes, bits: int):
+    """Bg int32 byte planes -> P = 8*Bg/bits int32 code planes.
+
+    Code ``p`` of a group sits at bit ``p*bits`` of the group's
+    little-endian bytes (the ``core.pack`` layout), so each code plane is
+    a shift of one byte plane, OR'd with the next plane's low bits where
+    the code straddles a byte.
+    """
+    mask = (1 << bits) - 1
+    out = []
+    for p in range(len(byte_planes) * 8 // bits):
+        i, s = divmod(p * bits, 8)
+        c = byte_planes[i] >> s
+        if s + bits > 8:
+            c = c | (byte_planes[i + 1] << (8 - s))
+        out.append(c & mask)
+    return out
+
+
+def expand_rows(a, rows: int):
+    """(kb, n) -> (kb*rows, n): repeat every row ``rows`` times (sublane
+    broadcast; each block's meta covers ``rows`` consecutive plane rows)."""
+    kb, n = a.shape
+    return jnp.broadcast_to(a[:, None, :], (kb, rows, n)).reshape(kb * rows, n)
+
+
+def decode_planes(byte_planes, meta, fmt: BlockFormat):
+    """Dequantize one plane-layout tile: Bg int32 byte planes (R, n) and
+    the tile's per-block meta (R*P/block_size, n) -> P f32 (R, n) planes.
+
+    Row r of code plane p is element ``(r % rows) * P + p`` of block
+    ``r // rows`` (``rows = block_size / P``).
+    """
+    p_n = len(byte_planes) * 8 // fmt.bits
+    rows = fmt.block_size // p_n
+    m = expand_rows(meta.astype(jnp.int32), rows)
+    r_in = (jax.lax.broadcasted_iota(jnp.int32, m.shape, 0) & (rows - 1)
+            if fmt.ox else None)
+    return [decode_values(c, m, None if r_in is None else r_in * p_n + p, fmt)
+            for p, c in enumerate(unpack_planes(byte_planes, fmt.bits))]

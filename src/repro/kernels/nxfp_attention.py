@@ -8,11 +8,19 @@ wall time ~ KV bytes / HBM bandwidth, so streaming 4.34-bit codes instead of
 kernel is the paper's "smaller memory footprint" claim turned into serving
 bandwidth.
 
-Layout (from ``QTensor.quantize(k, fmt, axis=-1)`` per cache):
+Storage (from ``QTensor.quantize(k, fmt, axis=-1)`` per cache):
   k_packed/v_packed: (B, S, KVH, NB, bpb) uint8    NB = head_dim/32
-  k_meta/v_meta:     (B, S, KVH, NB)      int32
+  k_meta/v_meta:     (B, S, KVH, NB)      uint16
   q:                 (B, KVH, G, D)                G = q_heads / kv_heads
-  lengths:           (B, 1) int32                  valid cache length per seq
+  lengths:           (B,) int32                    valid cache length per seq
+
+Kernel view (``cache_planes``; DESIGN.md §2.4): head_dim runs down the
+sublanes and the context along the lanes — byte planes ``(B, KVH, Bg,
+D/P, S)`` and meta ``(B, KVH, NB, S)`` — so every block is lane-dense and
+the block scale broadcasts down sublanes.  Code plane p holds head_dim
+indices ``P*j + p``: q is split the same way for the scores, and the
+output comes back per plane and is re-interleaved by the wrapper.
+``lengths`` rides scalar prefetch (SMEM).
 
 Grid: (B, KVH, S/TS); the context axis is sequential with the classic
 online-softmax (m, l, acc) VMEM carry.
@@ -27,24 +35,31 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import BlockFormat
-from .decode_lib import decode_block_values, unpack_codes_pallas
+from .decode_lib import code_group, decode_planes
 
-__all__ = ["nxfp_decode_attention_pallas"]
+__all__ = ["nxfp_decode_attention_pallas", "cache_planes"]
 
 _NEG_INF = -1e30
 
 
-def _dequant_tile(p_ref, m_ref, fmt: BlockFormat):
-    """(1, TS, 1, NB, bpb) packed + (1, TS, 1, NB) meta -> (TS, D) f32."""
-    codes = unpack_codes_pallas(p_ref[0, :, 0], fmt.bits)   # (TS, NB, 32)
-    vals = decode_block_values(codes, m_ref[0, :, 0], fmt)  # (TS, NB, 32)
-    ts, nb, b = vals.shape
-    return vals.reshape(ts, nb * b)
+def cache_planes(packed, meta, bg: int):
+    """(B, S, KVH, NB, bpb) + (B, S, KVH, NB) -> ((B, KVH, Bg, D/P, S),
+    (B, KVH, NB, S)) for groups of ``bg`` bytes."""
+    b, s, kvh, nb, bpb = packed.shape
+    planes = packed.reshape(b, s, kvh, nb * bpb // bg, bg) \
+        .transpose(0, 2, 4, 3, 1)
+    return planes, meta.transpose(0, 2, 3, 1)
 
 
-def _kernel(q_ref, kp_ref, km_ref, vp_ref, vm_ref, len_ref, o_ref,
+def _dequant(p_ref, m_ref, fmt: BlockFormat):
+    """One (Bg, D/P, TS) packed tile + (NB, TS) meta -> P f32 (D/P, TS)."""
+    b = p_ref[0, 0].astype(jnp.int32)
+    return decode_planes([b[q] for q in range(b.shape[0])], m_ref[0, 0], fmt)
+
+
+def _kernel(len_ref, q_ref, kp_ref, km_ref, vp_ref, vm_ref, o_ref,
             m_scr, l_scr, acc_scr, *, fmt: BlockFormat, tile_s: int):
-    s_idx = pl.program_id(2)
+    b_idx, s_idx = pl.program_id(0), pl.program_id(2)
 
     @pl.when(s_idx == 0)
     def _init():
@@ -52,33 +67,33 @@ def _kernel(q_ref, kp_ref, km_ref, vp_ref, vm_ref, len_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)                     # (G, D)
-    k = _dequant_tile(kp_ref, km_ref, fmt)                  # (TS, D)
-    scores = jax.lax.dot_general(                           # (G, TS)
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    k_planes = _dequant(kp_ref, km_ref, fmt)                # P x (D/P, TS)
+    scores = None                                           # (G, TS)
+    for p, k in enumerate(k_planes):
+        sp = jax.lax.dot(q_ref[0, 0, p], k, preferred_element_type=jnp.float32)
+        scores = sp if scores is None else scores + sp
 
-    pos = s_idx * tile_s + jax.lax.iota(jnp.int32, tile_s)
-    valid = pos < len_ref[0, 0]
-    scores = jnp.where(valid[None, :], scores, _NEG_INF)
+    pos = s_idx * tile_s + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    valid = pos < len_ref[b_idx]
+    scores = jnp.where(valid, scores, _NEG_INF)
 
     m_old = m_scr[...]                                      # (G, 1)
     m_new = jnp.maximum(m_old, jnp.max(scores, axis=-1, keepdims=True))
     alpha = jnp.exp(m_old - m_new)
-    p = jnp.exp(scores - m_new)                             # (G, TS)
-    p = jnp.where(valid[None, :], p, 0.0)
+    pr = jnp.where(valid, jnp.exp(scores - m_new), 0.0)     # (G, TS)
 
-    v = _dequant_tile(vp_ref, vm_ref, fmt)                  # (TS, D)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    for p, v in enumerate(_dequant(vp_ref, vm_ref, fmt)):   # (D/P, TS)
+        acc_scr[p] = acc_scr[p] * alpha + jax.lax.dot_general(
+            pr, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(pr, axis=-1, keepdims=True)
     m_scr[...] = m_new
 
     @pl.when(s_idx == pl.num_programs(2) - 1)
     def _flush():
-        o_ref[0, 0] = (acc_scr[...] /
-                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        inv = 1.0 / jnp.maximum(l_scr[...], 1e-30)
+        for p in range(acc_scr.shape[0]):
+            o_ref[0, 0, p] = (acc_scr[p] * inv).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -91,30 +106,36 @@ def nxfp_decode_attention_pallas(q, k_packed, k_meta, v_packed, v_meta,
     bb, s, kvh2, nb, bpb = k_packed.shape
     assert (bb, kvh2) == (b, kvh) and nb * fmt.block_size == d
     assert s % tile_s == 0, (s, tile_s)
-    # 5/6-bit dequant consumes two-block pack tiles along head_dim
-    assert fmt.bits in (4, 8) or nb % 2 == 0, (fmt.bits, nb)
+    p_n, bg = code_group(fmt.bits)
+    dp = d // p_n
 
-    grid = (b, kvh, s // tile_s)
-    kv_spec = pl.BlockSpec((1, tile_s, 1, nb, bpb),
-                           lambda i, j, k: (i, k, j, 0, 0))
-    meta_spec = pl.BlockSpec((1, tile_s, 1, nb),
-                             lambda i, j, k: (i, k, j, 0))
+    kp, km = cache_planes(k_packed, k_meta, bg)
+    vp, vm = cache_planes(v_packed, v_meta, bg)
+    # q plane p holds head_dim indices P*j + p, matching the code planes
+    qp = q.astype(jnp.float32).reshape(b, kvh, g, dp, p_n) \
+        .transpose(0, 1, 4, 2, 3)                           # (B, KVH, P, G, D/P)
+
+    q_spec = pl.BlockSpec((1, 1, p_n, g, dp),
+                          lambda i, j, k, lens: (i, j, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, 1, bg, dp, tile_s),
+                           lambda i, j, k, lens: (i, j, 0, 0, k))
+    meta_spec = pl.BlockSpec((1, 1, nb, tile_s),
+                             lambda i, j, k, lens: (i, j, 0, k))
     out = pl.pallas_call(
         functools.partial(_kernel, fmt=fmt, tile_s=tile_s),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda i, j, k: (i, j, 0, 0)),
-            kv_spec, meta_spec, kv_spec, meta_spec,
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda i, j, k: (i, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, kvh, s // tile_s),
+            in_specs=[q_spec, kv_spec, meta_spec, kv_spec, meta_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((g, 1), jnp.float32),
+                pltpu.VMEM((g, 1), jnp.float32),
+                pltpu.VMEM((p_n, g, dp), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, p_n, g, dp), jnp.float32),
         interpret=interpret,
-    )(q, k_packed, k_meta.astype(jnp.int32),
-      v_packed, v_meta.astype(jnp.int32), lengths.astype(jnp.int32))
-    return out
+        name="nxfp_decode_attention",
+    )(lengths.reshape(b).astype(jnp.int32), qp, kp, km, vp, vm)
+    # (B, KVH, P, G, D/P) -> (B, KVH, G, D): re-interleave the planes
+    return out.transpose(0, 1, 3, 4, 2).reshape(b, kvh, g, d)

@@ -4,24 +4,29 @@ Computes ``y = dequant(Xq) @ dequant(Wq)`` where BOTH operands stream
 *packed* HBM -> VMEM: the activation tensor is quantized along its feature
 (contraction) axis by the fused quantizer (``nxfp_quantize.py``, AMXFP/ox
 activation formats), the weight along axis 0 of its (K, N) layout as in
-``nxfp_matmul.py``. Each grid step decodes one activation row-block tile
-and one weight row-block tile arithmetically on the VPU (dual decode tile)
-and feeds the MAC on the MXU — prefill GEMM HBM traffic drops to
-``(bits_x + bits_w)/32`` of the bf16 baseline and the separate
-dequant->matmul round trip for activations disappears.
+``nxfp_matmul.py``. Each grid step decodes one activation tile and one
+weight tile arithmetically on the VPU (dual decode tile) and feeds the MAC
+on the MXU — prefill GEMM HBM traffic drops to ``(bits_x + bits_w)/32`` of
+the bf16 baseline and the separate dequant->matmul round trip for
+activations disappears.
 
-Memory layout (both produced by ``quantize_qtensor``):
+Storage (both produced by ``quantize_qtensor``):
 
   x packed: (M, KB, bpb_x) uint8   blocks along the contraction dim
   x meta:   (M, KB) uint16/uint32  (int32 in-kernel; asym meta is 26 bits)
   w packed: (N, KB, bpb_w) uint8
   w meta:   (N, KB) uint16
 
-Tiling: grid (M/TM, N/TN, K/TK), K innermost; TK a multiple of the (shared)
-quantization block size so blocks never straddle a VMEM tile, and of the
-two-block pack tile for 5/6-bit widths (ops.py picks tiles that satisfy
-BOTH formats). Zero-padded packed rows decode to exact zeros (meta 0 keeps
-the ox substitution gate off), so M padding is free.
+Kernel view: both operands in the plane layout of ``nxfp_matmul.py`` (K
+down the sublanes), split into the SAME P code planes — P is the lcm of
+the two widths' group sizes (``code_group(bits, p_min)``) — so plane p of
+each operand holds K indices ``P*j + p`` and the product is the sum over
+planes of ``dequant(x_p)^T @ dequant(w_p)``.
+
+Tiling: grid (M/TM, N/TN, K/TK), K innermost; TM/TN multiples of 128 (or
+the whole dim), TK/P a multiple of 8 and TK/32 of 16 (or K).  Zero-padded
+packed rows decode to exact zeros (meta 0 keeps the ox substitution gate
+off), so M padding is free.
 """
 from __future__ import annotations
 
@@ -33,21 +38,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import BlockFormat
-from .decode_lib import decode_block_values, unpack_codes_pallas
+from .decode_lib import code_group, decode_planes
+from .nxfp_matmul import weight_planes
 
-__all__ = ["nxfp_qq_matmul_pallas"]
+__all__ = ["nxfp_qq_matmul_pallas", "qq_planes"]
 
 
-def _decode_tile(p_ref, m_ref, fmt: BlockFormat):
-    """Dequantize one (R, KB_t, bpb) packed tile to a bf16 (R, TK) tile.
+def qq_planes(x_fmt: BlockFormat, w_fmt: BlockFormat):
+    """(P, Bg_x, Bg_w): the shared plane count and each operand's group."""
+    p_n = code_group(w_fmt.bits, code_group(x_fmt.bits)[0])[0]
+    return p_n, code_group(x_fmt.bits, p_n)[1], code_group(w_fmt.bits, p_n)[1]
 
-    Shared by both operands; ``decode_block_values`` dispatches to the
-    extended arithmetic decode for asym/ox activation formats.
-    """
-    codes = unpack_codes_pallas(p_ref[...], fmt.bits)        # (R, KB_t, B)
-    vals = decode_block_values(codes, m_ref[...], fmt)
-    r, kb, b = vals.shape
-    return vals.reshape(r, kb * b).astype(jnp.bfloat16)      # (R, TK)
+
+def _dequant(p_ref, m_ref, fmt: BlockFormat):
+    b = p_ref[...].astype(jnp.int32)                         # (Bg, TK/P, T)
+    return decode_planes([b[q] for q in range(b.shape[0])], m_ref[...], fmt)
 
 
 def _kernel(xp_ref, xm_ref, wp_ref, wm_ref, o_ref, acc_ref, *,
@@ -58,12 +63,14 @@ def _kernel(xp_ref, xm_ref, wp_ref, wm_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    xt = _decode_tile(xp_ref, xm_ref, x_fmt)                 # (TM, TK) bf16
-    wt = _decode_tile(wp_ref, wm_ref, w_fmt)                 # (TN, TK) bf16
-    acc_ref[...] += jax.lax.dot_general(
-        xt, wt,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc = acc_ref[...]
+    for xt, wt in zip(_dequant(xp_ref, xm_ref, x_fmt),      # (TK/P, TM)
+                      _dequant(wp_ref, wm_ref, w_fmt)):     # (TK/P, TN)
+        acc += jax.lax.dot_general(
+            xt.astype(jnp.bfloat16), wt.astype(jnp.bfloat16),
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    acc_ref[...] = acc
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _flush():
@@ -96,26 +103,25 @@ def nxfp_qq_matmul_pallas(x_packed, x_meta, w_packed, w_meta,
         x_packed = jnp.pad(x_packed, ((0, pad_m), (0, 0), (0, 0)))
         x_meta = jnp.pad(x_meta, ((0, pad_m), (0, 0)))
     assert k_dim % tile_k == 0 and n % tile_n == 0, (k_dim, n, tile_k, tile_n)
-    kb_t = tile_k // x_fmt.block_size
-    # 5/6-bit dequant consumes two-block (64-code) pack tiles: every K tile
-    # must hold an even number of quantization blocks for EACH such operand
-    for f in (x_fmt, w_fmt):
-        assert f.bits in (4, 8) or kb_t % 2 == 0, (f.bits, tile_k)
+    p_n, bg_x, bg_w = qq_planes(x_fmt, w_fmt)
+    xp, xm = weight_planes(x_packed, x_meta.astype(jnp.int32), bg_x)
+    wp, wm = weight_planes(w_packed, w_meta, bg_w)
+    tkp, kb_t = tile_k // p_n, tile_k // x_fmt.block_size
 
     grid = ((m + pad_m) // tile_m, n // tile_n, k_dim // tile_k)
     out = pl.pallas_call(
         functools.partial(_kernel, x_fmt=x_fmt, w_fmt=w_fmt),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_m, kb_t, bpb_x), lambda i, j, k: (i, k, 0)),
-            pl.BlockSpec((tile_m, kb_t), lambda i, j, k: (i, k)),
-            pl.BlockSpec((tile_n, kb_t, bpb_w), lambda i, j, k: (j, k, 0)),
-            pl.BlockSpec((tile_n, kb_t), lambda i, j, k: (j, k)),
+            pl.BlockSpec((bg_x, tkp, tile_m), lambda i, j, k: (0, k, i)),
+            pl.BlockSpec((kb_t, tile_m), lambda i, j, k: (k, i)),
+            pl.BlockSpec((bg_w, tkp, tile_n), lambda i, j, k: (0, k, j)),
+            pl.BlockSpec((kb_t, tile_n), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((tile_m, tile_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m + pad_m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((tile_m, tile_n), jnp.float32)],
         interpret=interpret,
-    )(x_packed, x_meta.astype(jnp.int32),
-      w_packed, w_meta.astype(jnp.int32))
+        name="nxfp_qq_matmul",
+    )(xp, xm, wp, wm)
     return out[:m]

@@ -21,11 +21,9 @@ HBM -> separate XLA repack), this kernel eliminates:
     it replaces.
 
 Element widths 4/5/6/8. 4/8-bit codes pack with a single routing matmul
-(never straddle a byte); 5/6-bit codes straddle, so they pack over the
-two-block (64-code, 40/48-byte) tile of ``core.pack.pack_tile`` with the
-low/spill routing pair — same layout, still scatter-free (DESIGN.md
-§2.4). 3-bit and custom-recycle sweeps take the XLA arithmetic fallback
-in ops.py. Used on TPU for runtime casts that sit on the critical path:
+(never straddle a byte); 5/6-bit codes straddle, so they add the spill
+route — same layout, still scatter-free (DESIGN.md §2.4). 3-bit and
+custom-recycle sweeps take the XLA arithmetic fallback in ops.py. Used on TPU for runtime casts that sit on the critical path:
 per-step KV cache quantization and NxFP gradient compression before the
 pod-axis all-reduce.
 """
@@ -38,7 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.formats import BlockFormat
-from repro.core.pack import bytes_per_block, pack_tile
+from repro.core.pack import bytes_per_block
 from repro.core.quantize import arith_encode_blocks
 from .decode_lib import byte_routes
 
@@ -53,37 +51,22 @@ def _kernel(x_ref, packed_ref, meta_ref, *, fmt: BlockFormat):
     bpb = block_size * bits // 8
     if bits == 8:
         packed = best_codes
-    elif bits == 4:
-        # in-kernel sub-byte pack: shift each code to its in-byte offset,
-        # then route to byte slots with a constant (B, bpb) 0/1 matmul —
-        # disjoint bit-fields, so the f32 sum is an exact bitwise OR. No
-        # spill term: byte-aligned widths (4-bit) never straddle a byte.
-        off = (jax.lax.broadcasted_iota(jnp.int32, xb.shape, 1) * bits) % 8
-        shifted = (best_codes << off).astype(jnp.float32)
-        lo_route, _ = byte_routes(block_size, bits, bpb, code_axis=0)
-        packed = jax.lax.dot(shifted, lo_route,
-                             preferred_element_type=jnp.float32
-                             ).astype(jnp.int32)
     else:
-        # 5/6-bit: codes straddle bytes, so the pack runs over the
-        # two-block (64-code, 40/48-byte) tile (core.pack.pack_tile) with
-        # the spill routing term of core.pack.pack_layout: each code
-        # contributes (code << off) & 0xFF to its low byte and
-        # (code << off) >> 8 to the next. Pairing adjacent rows (blocks)
-        # is layout-neutral — block_size*bits is a whole number of bytes,
-        # so the two-block little-endian layout is exactly the
-        # concatenation of the per-block layouts.
-        rows = best_codes.shape[0]
-        n_codes, n_bytes = pack_tile(bits, block_size)
-        c2 = best_codes.reshape(rows // 2, n_codes)
-        off = (jax.lax.broadcasted_iota(jnp.int32, c2.shape, 1) * bits) % 8
-        shifted = c2 << off
-        lo_route, hi_route = byte_routes(n_codes, bits, n_bytes, code_axis=0)
-        packed = (jax.lax.dot((shifted & 0xFF).astype(jnp.float32), lo_route,
-                              preferred_element_type=jnp.float32) +
-                  jax.lax.dot((shifted >> 8).astype(jnp.float32), hi_route,
-                              preferred_element_type=jnp.float32)
-                  ).astype(jnp.int32).reshape(rows, bpb)
+        # in-kernel sub-byte pack: shift each code to its in-byte offset,
+        # then route to byte slots with constant (B, bpb) 0/1 matmuls —
+        # disjoint bit-fields, so the f32 sums are exact bitwise ORs. A
+        # straddling code (5/6-bit) sends its high bits to the next byte
+        # through the spill route (core.pack.pack_layout).
+        off = (jax.lax.broadcasted_iota(jnp.int32, xb.shape, 1) * bits) % 8
+        shifted = best_codes << off
+        lo_route, hi_route = byte_routes(block_size, bits, bpb)
+        packed = jax.lax.dot((shifted & 0xFF).astype(jnp.float32), lo_route,
+                             preferred_element_type=jnp.float32)
+        if 8 % bits:
+            packed += jax.lax.dot((shifted >> 8).astype(jnp.float32),
+                                  hi_route,
+                                  preferred_element_type=jnp.float32)
+        packed = packed.astype(jnp.int32)
     packed_ref[...] = packed.astype(jnp.uint8)
     meta_ref[...] = best_meta[:, None]
 
@@ -101,8 +84,6 @@ def nxfp_quantize_pack_pallas(xb, fmt: BlockFormat, tile_rows: int = 256,
     t, b = xb.shape
     assert b == fmt.block_size
     assert fmt.bits in (4, 5, 6, 8), fmt
-    # 5/6-bit packs over two-block tiles: row pairs must not cross a grid tile
-    assert fmt.bits in (4, 8) or tile_rows % 2 == 0, (fmt.bits, tile_rows)
     assert not fmt.cr or fmt.recycle == "half_smallest", fmt
     bpb = bytes_per_block(b, fmt.bits)
     pad = (-t) % tile_rows
@@ -122,5 +103,6 @@ def nxfp_quantize_pack_pallas(xb, fmt: BlockFormat, tile_rows: int = 256,
             jax.ShapeDtypeStruct((t + pad, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="nxfp_quantize",
     )(xb.astype(jnp.float32))
     return packed[:t], meta[:t, 0].astype(jnp.dtype(fmt.meta_dtype))

@@ -21,9 +21,10 @@ from repro.core.qtensor import QTensor, fmt_key
 from repro.core.quantize import (quantize_blocks, quantize_blocks_arith,
                                  to_blocks)
 from . import ref as kref
+from .decode_lib import code_group
 from .nxfp_attention import nxfp_decode_attention_pallas
 from .nxfp_matmul import nxfp_matmul_pallas
-from .nxfp_qq_matmul import nxfp_qq_matmul_pallas
+from .nxfp_qq_matmul import nxfp_qq_matmul_pallas, qq_planes
 from .nxfp_quantize import nxfp_quantize_pack_pallas
 
 __all__ = ["qmatmul", "quantize_qtensor", "decode_attention"]
@@ -58,28 +59,41 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-# Pallas-eligible element widths (DESIGN.md §2.5): 4/8-bit pack per block;
-# 5/6-bit pack/unpack over the two-block (64-code, 40/48-byte) tile.
+# Pallas-eligible element widths (DESIGN.md §2.5): every width whose code
+# group (``decode_lib.code_group``) fits a block — 4/8-bit codes sit inside
+# one byte, 5/6-bit codes straddle bytes within 5/3-byte groups.
 _KERNEL_BITS = (4, 5, 6, 8)
 
 
-def _tile_ok(fmt: BlockFormat, n_blocks: int) -> bool:
-    """Can the dequant kernels consume this packed block count?
-
-    5/6-bit kernels read two-block (64-code) pack tiles, so the packed
-    block count along the quantized axis must be even; odd counts take
-    the XLA path.
-    """
-    if fmt.bits in (4, 8):
-        return True
-    return fmt.bits in (5, 6) and n_blocks % 2 == 0
+def _arith_ok(fmt: BlockFormat) -> bool:
+    """The arithmetic codecs — encoders and the kernels' decode — hard-code
+    the default CR remap (DESIGN.md §2.3)."""
+    return not fmt.cr or fmt.recycle == "half_smallest"
 
 
-def _pick_tile(dim: int, prefs=(512, 256, 128, 64, 32)) -> Optional[int]:
-    for t in prefs:
-        if dim % t == 0:
-            return t
-    return None
+def _kernel_ok(fmt: BlockFormat) -> bool:
+    return fmt.bits in _KERNEL_BITS and _arith_ok(fmt)
+
+
+def _tile(dim: int, prefs) -> int:
+    """Largest preferred tile dividing ``dim``, else the whole dim.
+
+    Every preference keeps the kernel blocks (8, 128)-aligned; a whole
+    dim is always a legal block."""
+    return next((t for t in prefs if dim % t == 0), dim)
+
+
+def _tile_m(m: int) -> int:
+    """Row tile: one tile up to 256 rows (block == padded array), else 256."""
+    return -(-m // 8) * 8 if m <= 256 else 256
+
+
+def _tile_k(k: int, p_n: int, lane_dense: bool) -> int:
+    """Contraction tile: TK/32 meta rows a multiple of 16 (uint16 tiling)
+    and TK/P plane rows a multiple of 32 (uint8 tiling) — or of 128 where
+    the activation planes put TK/P on lanes (``lane_dense``)."""
+    step = 128 if lane_dense else 32
+    return _tile(k, tuple(t for t in (1024, 512) if (t // p_n) % step == 0))
 
 
 def qmatmul(x, w, impl: Optional[str] = None):
@@ -119,19 +133,13 @@ def qmatmul(x, w, impl: Optional[str] = None):
     if x2.shape[-1] < k_pad:  # quantization padded K to a block multiple
         x2 = jnp.pad(x2, ((0, 0), (0, k_pad - x2.shape[-1])))
 
-    if impl == "pallas" and w.fmt.bits in _KERNEL_BITS and _tile_ok(w.fmt, kb):
-        # 5/6-bit K tiles must hold two-block pack tiles (an even number of
-        # quantization blocks)
-        two = 2 * w.fmt.block_size
-        tk = _pick_tile(k_pad) if w.fmt.bits in (4, 8) else _pick_tile(
-            k_pad, tuple(t for t in (512, 256, 128, 64, 32) if t % two == 0))
-        tn = _pick_tile(n, (256, 128, 64, 32, 16, 8))
-        if tk and tn:
-            tm = _pick_tile(max(x2.shape[0], 1), (256, 128, 64, 32, 16, 8, 1))
-            y = nxfp_matmul_pallas(x2, w.packed, w.meta, w.fmt,
-                                   tile_m=tm or 8, tile_n=tn, tile_k=tk,
-                                   interpret=_interpret())
-            return y.reshape(*lead, n)
+    if impl == "pallas" and _kernel_ok(w.fmt):
+        y = nxfp_matmul_pallas(
+            x2, w.packed, w.meta, w.fmt, tile_m=_tile_m(x2.shape[0]),
+            tile_n=_tile(n, (512, 256, 128)),
+            tile_k=_tile_k(k_pad, code_group(w.fmt.bits)[0], True),
+            interpret=_interpret())
+        return y.reshape(*lead, n)
     y = kref.qmatmul_ref(x2, w.packed, w.meta, w.fmt)
     return y.reshape(*lead, n)
 
@@ -155,31 +163,15 @@ def _qact_matmul(xq: QTensor, w, impl: Optional[str]):
     n = w.packed.shape[0]
     k_pad = kb * x_fmt.block_size
 
-    if impl == "pallas" and x_fmt.bits in _KERNEL_BITS \
-            and w_fmt.bits in _KERNEL_BITS \
-            and _tile_ok(x_fmt, kb) and _tile_ok(w_fmt, kb):
-        # K tiles must hold whole two-block pack tiles for EVERY 5/6-bit
-        # operand (the stricter of the two constraints wins)
-        prefs = (512, 256, 128, 64, 32)
-        for f in (x_fmt, w_fmt):
-            if f.bits in (5, 6):
-                two = 2 * f.block_size
-                prefs = tuple(t for t in prefs if t % two == 0)
-        tk = _pick_tile(k_pad, prefs)
-        tn = _pick_tile(n, (256, 128, 64, 32, 16, 8))
-        if tk and tn:
-            tm = _pick_tile(max(xp.shape[0], 1), (256, 128, 64, 32, 16, 8, 1))
-            y = nxfp_qq_matmul_pallas(xp, xm, w.packed, w.meta, x_fmt, w_fmt,
-                                      tile_m=tm or 8, tile_n=tn, tile_k=tk,
-                                      interpret=_interpret())
-            return y.reshape(*lead, n)
+    if impl == "pallas" and _kernel_ok(x_fmt) and _kernel_ok(w_fmt):
+        y = nxfp_qq_matmul_pallas(
+            xp, xm, w.packed, w.meta, x_fmt, w_fmt,
+            tile_m=_tile_m(xp.shape[0]), tile_n=_tile(n, (512, 256, 128)),
+            tile_k=_tile_k(k_pad, qq_planes(x_fmt, w_fmt)[0], False),
+            interpret=_interpret())
+        return y.reshape(*lead, n)
     y = kref.qq_matmul_ref(xp, xm, x_fmt, w.packed, w.meta, w_fmt)
     return y.reshape(*lead, n)
-
-
-def _arith_ok(fmt: BlockFormat) -> bool:
-    """Arithmetic encoders hard-code the default CR remap (DESIGN.md §2.3)."""
-    return not fmt.cr or fmt.recycle == "half_smallest"
 
 
 def quantize_qtensor(x, fmt, axis: int = -1,
@@ -188,7 +180,7 @@ def quantize_qtensor(x, fmt, axis: int = -1,
 
     ``impl="pallas"`` (4/5/6/8-bit): one fused kernel emits packed uint8 +
     uint16 meta directly — no int32 codes ever reach HBM and no separate
-    repack pass runs (5/6-bit packs over the two-block tile, §2.4).
+    repack pass runs (§2.4).
     Everything else (non-TPU backends, 3-bit, custom recycle sweeps) takes
     the XLA path: the arithmetic encoder + the gather/scatter-free
     shift-or pack.
@@ -206,7 +198,7 @@ def quantize_qtensor(x, fmt, axis: int = -1,
         codes, meta = quantize_blocks(xb, fmt)
         return QTensor(pack_codes_scatter(codes, fmt.bits), meta, key,
                        tuple(x.shape), axis, orig)
-    if impl == "pallas" and fmt.bits in _KERNEL_BITS and _arith_ok(fmt):
+    if impl == "pallas" and _kernel_ok(fmt):
         flat = xb.reshape(-1, fmt.block_size)
         packed, meta = nxfp_quantize_pack_pallas(
             flat.astype(jnp.float32), fmt, interpret=_interpret())
@@ -242,13 +234,11 @@ def decode_attention(q, kq: QTensor, vq: QTensor, lengths, n_kv_heads: int,
     d_pad = kq.packed.shape[-2] * fmt.block_size
     if d_pad != d:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, d_pad - d)))
-    if impl == "pallas" and fmt.bits in _KERNEL_BITS and \
-            _tile_ok(fmt, kq.packed.shape[-2]):
-        s = kq.packed.shape[1]
-        ts = _pick_tile(s, (512, 256, 128, 64, 32, 16, 8, 1))
+    if impl == "pallas" and _kernel_ok(fmt):
         out = nxfp_decode_attention_pallas(
             qg, kq.packed, kq.meta, vq.packed, vq.meta, lengths2, fmt,
-            tile_s=ts, interpret=_interpret())
+            tile_s=_tile(kq.packed.shape[1], (512, 256, 128)),
+            interpret=_interpret())
     else:
         out = kref.decode_attention_ref(
             qg, kq.packed, kq.meta, vq.packed, vq.meta, lengths2, fmt)

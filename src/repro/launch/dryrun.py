@@ -217,7 +217,11 @@ def run_one(arch: str, shape: str, mesh_name: str, *, baseline: bool,
 
 def _cell_subprocess(arch, shape, mesh_name, baseline, n_micro, fsdp,
                      compress_mode) -> int:
-    """Isolate each cell: an XLA CHECK-abort must not kill the sweep."""
+    """Isolate each cell: an XLA CHECK-abort must not kill the sweep.
+
+    The child is pinned to the CPU: its 512 placeholder devices are host
+    devices, and a chip belongs to one process at a time.
+    """
     import subprocess
     import sys
     cmd = [sys.executable, "-m", "repro.launch.dryrun",
@@ -227,7 +231,8 @@ def _cell_subprocess(arch, shape, mesh_name, baseline, n_micro, fsdp,
         cmd.append("--baseline")
     if fsdp is False:
         cmd.append("--no-fsdp")
-    r = subprocess.run(cmd, timeout=3000)
+    r = subprocess.run(cmd, timeout=3000,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
     return r.returncode
 
 

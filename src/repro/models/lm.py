@@ -13,6 +13,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.qtensor import QTensor, QuantPolicy, direct_cast_tree
+
 from .attention import memory_kv
 from .blocks import (init_layer, layer_decode, layer_forward,
                      layer_prefill_chunk, layer_verify)
@@ -59,6 +61,71 @@ def init_params(cfg: ModelConfig, key) -> Params:
     else:
         raise ValueError(fam)
     return p
+
+
+def init_cast_params(cfg: ModelConfig, key, policy: QuantPolicy,
+                     quantize_fn=None, mesh=None) -> Params:
+    """``direct_cast_tree(init_params(cfg, key), policy, quantize_fn)``,
+    built one layer at a time so the full float32 tree never exists.
+
+    Each layer is initialized from its own key of ``init_params``' layer
+    split, cast, and written into a preallocated stacked buffer in place;
+    the top-level leaves (embeddings, head, norms) come from the same
+    stream.  The result is bit-identical to casting the full tree under
+    ``jit``, at a peak of the cast tree plus one float32 layer.  With a
+    ``mesh`` every leaf is replicated over it (the serving engines'
+    weight layout) and each device casts its own replica; None leaves
+    them on the default device.  Families with one homogeneous layer
+    stack only.
+    """
+    if cfg.family not in _KIND:
+        raise ValueError(f"layer-wise cast init supports {sorted(_KIND)}, "
+                         f"not family={cfg.family!r}")
+    kind = _KIND[cfg.family]
+    layer_keys = jax.random.split(
+        split_keys(key, ["embed", "head", "layers", "enc", "cross",
+                         "pos"])["layers"], cfg.n_layers)
+
+    def cast(tree):
+        return direct_cast_tree(tree, policy, quantize_fn)
+
+    def top():
+        p = init_params(cfg, key)
+        p.pop("layers")                 # dead code: XLA never builds it
+        return cast(p)
+
+    # which stacked leaves the policy casts is decided on the stacked
+    # shapes (min_size, ndim), exactly as for the full tree
+    ref = jax.eval_shape(lambda: cast(init_params(cfg, key)))["layers"]
+    qfn = quantize_fn or (lambda x, f, a: QTensor.quantize(x, f, axis=a))
+
+    def one_layer(k):
+        layer = init_layer(k, cfg, kind)
+        return jax.tree.leaves({
+            n: (qfn(v, policy.weight_fmt, policy.axis)
+                if isinstance(ref[n], QTensor) else v)
+            for n, v in layer.items()})
+
+    def jit(fn, **kw):
+        if mesh is None:
+            return jax.jit(fn, **kw)
+        # fully manual: a Pallas quantizer cannot be partitioned by XLA,
+        # so every device runs the whole (replicated) computation itself
+        from jax.sharding import PartitionSpec
+        from repro.sharding.shard_map import shard_map_manual
+        return jax.jit(shard_map_manual(fn, mesh, in_specs=PartitionSpec(),
+                                        out_specs=PartitionSpec()), **kw)
+
+    leaves = jit(lambda: [jnp.zeros(l.shape, l.dtype)
+                          for l in jax.tree.leaves(ref)])()
+    put = jit(lambda st, lay, i: [a.at[i].set(b) for a, b in zip(st, lay)],
+              donate_argnums=0)
+    layer_fn = jit(one_layer)
+    for i in range(cfg.n_layers):
+        leaves = put(leaves, layer_fn(layer_keys[i]), jnp.int32(i))
+    params = jit(top)()
+    params["layers"] = jax.tree.unflatten(jax.tree.structure(ref), leaves)
+    return params
 
 
 def _embed(cfg: ModelConfig, params: Params, tokens):

@@ -2000,6 +2000,17 @@ class ContinuousEngine:
         k, n_rounds = self._spec_round_shape()
         return n_rounds * (k + 1)
 
+    def chunk_args(self, poison):
+        """The decode chunk's argument row after ``params``: host slot
+        vectors (uploaded), the device cache, and the (B,) ``poison``
+        mask.  ``_chunk_jit(params, *chunk_args(...), n_steps=chunk,
+        greedy=...)`` is the exact decode program ``serve`` dispatches."""
+        return (jnp.asarray(self._tok), self.cache,
+                jnp.asarray(self._keys), jnp.asarray(self._done),
+                jnp.asarray(self._n_gen), jnp.asarray(self._max_new),
+                jnp.asarray(self._temp), jnp.asarray(self._stop),
+                self._decode_live(), jnp.asarray(poison))
+
     def _dispatch_chunk(self, poison):
         """Run one decode chunk and fold the results into host slot state.
 
@@ -2012,11 +2023,7 @@ class ContinuousEngine:
         ``n_rounds * (k+1)``), which the harvest loop never notices: it
         reads each slot's ``n_gen`` delta off the packed prefix.
         """
-        args = (jnp.asarray(self._tok), self.cache,
-                jnp.asarray(self._keys), jnp.asarray(self._done),
-                jnp.asarray(self._n_gen), jnp.asarray(self._max_new),
-                jnp.asarray(self._temp), jnp.asarray(self._stop),
-                self._decode_live(), jnp.asarray(poison))
+        args = self.chunk_args(poison)
         greedy = bool((self._temp == 0.0).all())
         if self.speculative is None:
             (emitted, tok, self.cache, keys, done, n_gen,

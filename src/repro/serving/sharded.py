@@ -380,10 +380,10 @@ class ShardedContinuousEngine(ContinuousEngine):
         The per-shard decode workload is ``slots_per_shard`` slots
         through the UNSHARDED chunk program (keyed with mesh None, so
         it's shared with any unsharded engine on this config), against a
-        throwaway single-device cache, with params pinned to one device
-        — both sides of the stall-budget ratio then measure the same
-        regime, free of the GSPMD resharding a mesh-placed input would
-        drag into the timings.
+        throwaway single-device cache, with params read from one
+        device's replica — both sides of the stall-budget ratio then
+        measure the same regime, free of the GSPMD resharding a
+        mesh-placed input would drag into the timings.
         """
         cfg, kv = self.cfg, self._kv
         fn = cached_program(
@@ -392,8 +392,12 @@ class ShardedContinuousEngine(ContinuousEngine):
                 ContinuousEngine._chunk_fn, cfg=cfg, kv_fmt=kv),
                 static_argnames=("n_steps", "greedy")))
         b = self.slots_per_shard
-        dev = jax.devices()[0]
-        params = jax.device_put(self.params, dev)
+        # the first mesh device's replica of the weights, not a copy: a
+        # second full weight set on one device would not fit beside it
+        dev = self.mesh.devices.flat[0]
+        params = jax.tree.map(
+            lambda a: next(s.data for s in a.addressable_shards
+                           if s.device == dev), self.params)
         cache = jax.device_put(
             init_cache(cfg, b, self.max_len, kv), dev)
         return fn, params, cache, b

@@ -1,4 +1,4 @@
-"""shard_map construction across JAX API generations + backend gating.
+"""shard_map construction + backend gating.
 
 Two distinct shard_map shapes live in this repo, and they have very
 different backend support:
@@ -18,9 +18,8 @@ different backend support:
   ISSUE-2 multipod A/B, DESIGN.md §5 — so callers must gate on
   ``SHARD_MAP_WIRE_BACKENDS`` before tracing one.
 
-Both helpers paper over the JAX API split: the new API takes the
-*manual* axis set via ``axis_names``; older generations take the
-complement via ``auto`` (and ``check_rep`` instead of ``check_vma``).
+Both helpers build ``jax.shard_map``, which takes the *manual* axis set
+via ``axis_names``.
 """
 from __future__ import annotations
 
@@ -48,16 +47,9 @@ def shard_map_manual(body, mesh, in_specs, out_specs):
     Safe on all backends — no manual/auto subgroup mixing exists for the
     partitioner to choke on.
     """
-    try:
-        # new API (jax.shard_map): manual axes are named explicitly
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=set(mesh.axis_names),
-                             check_vma=False)
-    except (TypeError, AttributeError):
-        from jax.experimental.shard_map import shard_map
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=set(mesh.axis_names), check_vma=False)
 
 
 def shard_map_partial_auto(body, mesh, in_specs, out_specs,
@@ -68,17 +60,9 @@ def shard_map_partial_auto(body, mesh, in_specs, out_specs,
     GSPMD).  Callers MUST gate on ``partial_auto_ok()`` — the CPU
     partitioner hard-aborts (uncatchable CHECK) on partial-auto.
     """
-    try:
-        # AttributeError too: jax<0.5 has no jax.shard_map, and letting it
-        # escape silently demoted capable builds to the simulated wire
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=set(manual_axes), check_vma=False)
-    except (TypeError, AttributeError):
-        from jax.experimental.shard_map import shard_map
-        auto = frozenset(n for n in mesh.axis_names if n not in manual_axes)
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False, auto=auto)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=set(manual_axes),
+                         check_vma=False)
 
 
 def mesh_fingerprint(mesh):

@@ -246,3 +246,35 @@ def test_serve_engine_per_slot_vectors_host_device_identical():
                        chunk=4)
     np.testing.assert_array_equal(rh.tokens, rd.tokens)
     np.testing.assert_array_equal(rh.n_generated, rd.n_generated)
+
+
+def test_engine_serves_precast_params_identically():
+    """Weights cast one layer at a time (``init_cast_params``) equal the
+    whole-tree cast bit for bit, the engine leaves them as they are
+    (casting a cast tree is the identity), and greedy streams match an
+    engine handed the raw float32 tree."""
+    from repro.core.qtensor import QTensor, direct_cast_tree
+    from repro.kernels.ops import quantize_qtensor
+    from repro.models import init_cast_params
+    cfg = get_smoke_config("llama3_8b")
+    policy = QuantPolicy(weight_fmt="nxfp4", kv_fmt="nxfp4")
+    key = jax.random.PRNGKey(0)
+    whole = jax.jit(lambda: direct_cast_tree(
+        init_params(cfg, key), policy, quantize_qtensor))()
+    cast = init_cast_params(cfg, key, policy, quantize_fn=quantize_qtensor)
+    again = direct_cast_tree(cast, policy, quantize_qtensor)
+    assert isinstance(cast["layers"]["wq"], QTensor)
+    for other in (whole, again):
+        assert jax.tree.structure(other) == jax.tree.structure(cast)
+        for a, b in zip(jax.tree.leaves(other), jax.tree.leaves(cast)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    reqs = [Request(uid=i, tokens=p, max_new=6)
+            for i, p in enumerate(_prompts(cfg, 3, 8))]
+    kw = dict(n_slots=2, max_len=64, chunk=4)
+    raw = jax.jit(lambda: init_params(cfg, key))()
+    want = {r.uid: r.tokens for r in
+            ContinuousEngine(cfg, raw, policy, **kw).serve(reqs)}
+    got = {r.uid: r.tokens for r in
+           ContinuousEngine(cfg, cast, policy, **kw).serve(reqs)}
+    for uid, toks in want.items():
+        np.testing.assert_array_equal(got[uid], toks)

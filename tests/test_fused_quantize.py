@@ -22,9 +22,8 @@ from repro.core.pack import pack_codes_scatter
 from repro.kernels.nxfp_quantize import nxfp_quantize_pack_pallas
 from repro.kernels.ops import quantize_qtensor
 
-# every registered format family x width this repo exercises; 4/8-bit run
-# the fused Pallas kernel per block, 5/6-bit over the two-block (64-code)
-# pack tile (ISSUE-2), 3-bit the XLA arithmetic fallback
+# every registered format family x width this repo exercises; 4/5/6/8-bit
+# run the fused Pallas kernel per block, 3-bit the XLA arithmetic fallback
 REGISTRY = ["bfp4", "bfp4_cr", "mxfp4", "mxfp4_cr", "nxfp4", "nxfp4_nm",
             "nxfp4_nm_am", "nxfp4_bs16", "nxfp8", "mxfp8", "bfp8",
             "mxfp3", "nxfp5", "mxfp5", "nxfp6", "mxfp6", "mxfp6_e3m2"]
@@ -88,8 +87,8 @@ def test_arith_matches_searchsorted_reference(rng, fname):
 
 @pytest.mark.parametrize("fname", FALLBACK_FMTS)
 def test_xla_fallback_widths_roundtrip(rng, fname):
-    """Widths outside the kernel set (3-bit, now that 5/6-bit ride the
-    two-block tile) fall back to arith encode + shift-or pack, exactly."""
+    """Widths outside the kernel set (3-bit) fall back to arith encode +
+    shift-or pack, exactly."""
     fmt = get_format(fname)
     x = (rng.standard_normal((64, 96)) * 3).astype(np.float32)
     qt = quantize_qtensor(jnp.asarray(x), fname, axis=-1, impl="pallas")

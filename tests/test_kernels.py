@@ -74,8 +74,9 @@ def test_decode_attention_sweep(rng, fname, bshkd):
 
 @pytest.mark.parametrize("fname", ["nxfp5", "mxfp5", "nxfp6", "mxfp6_e3m2"])
 def test_matmul_kernel_two_block_widths(rng, fname):
-    """ISSUE-2: 5/6-bit weights route through the fused dequant GEMM via
-    the two-block (64-code, 40/48-byte) pack tile."""
+    """5/6-bit weights route through the fused dequant GEMM: codes
+    straddle bytes within 5/3-byte groups, one code plane per group
+    position."""
     fmt = get_format(fname)
     x = rng.standard_normal((17, 256)).astype(np.float32)
     w = (rng.standard_normal((256, 128)) * 0.05).astype(np.float32)
@@ -90,8 +91,7 @@ def test_matmul_kernel_two_block_widths(rng, fname):
 
 @pytest.mark.parametrize("fname", ["nxfp5", "nxfp6"])
 def test_decode_attention_two_block_widths(rng, fname):
-    """5/6-bit KV caches hit the Pallas decode-attention kernel (head_dim
-    64 = two 32-blocks = one pack tile)."""
+    """5/6-bit KV caches hit the Pallas decode-attention kernel."""
     b, s, h, kvh, d = 2, 64, 8, 4, 64
     q = rng.standard_normal((b, h, d)).astype(np.float32)
     k = (rng.standard_normal((b, s, kvh, d)) * 0.3).astype(np.float32)
@@ -108,15 +108,15 @@ def test_decode_attention_two_block_widths(rng, fname):
 
 
 def test_two_block_widths_odd_block_count_falls_back(rng):
-    """An odd number of 32-blocks can't tile into two-block pack tiles:
-    the wrappers must take the XLA path (not crash) and stay exact."""
+    """An odd number of 32-blocks (no longer special: code groups never
+    straddle a block) stays exact through the Pallas wrappers."""
     x = rng.standard_normal((8, 96)).astype(np.float32)   # 3 blocks
     w = (rng.standard_normal((96, 64)) * 0.1).astype(np.float32)
     qt = QTensor.quantize(jnp.asarray(w), "nxfp5", axis=0)
-    y = qmatmul(jnp.asarray(x), qt, impl="pallas")        # falls back
+    y = qmatmul(jnp.asarray(x), qt, impl="pallas")
     ref = x @ np.asarray(qt.dequantize(jnp.float32))[:96]
     np.testing.assert_allclose(np.asarray(y), ref, rtol=2e-2, atol=2e-2)
-    # head_dim 96 -> 3 blocks along the quantized axis: attention fallback
+    # head_dim 96 -> 3 blocks along the quantized axis
     q = rng.standard_normal((2, 4, 96)).astype(np.float32)
     k = (rng.standard_normal((2, 32, 2, 96)) * 0.2).astype(np.float32)
     kq = quantize_qtensor(jnp.asarray(k), "nxfp5", axis=-1, impl="xla")

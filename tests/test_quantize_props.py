@@ -169,16 +169,16 @@ def test_pack_roundtrip(bits, nblocks, seed):
        st.integers(min_value=0, max_value=2 ** 31 - 1))
 @settings(max_examples=60, deadline=None)
 def test_two_block_tile_pack_roundtrip(bits, npairs, seed):
-    """ISSUE-2: the 5/6-bit two-block (64-code, 40/48-byte) kernel tile.
+    """The 5/6-bit kernel layout, against the seed scatter oracle.
 
-    Against the seed scatter oracle: (a) shift-or pack == scatter pack,
-    (b) the gather-free Pallas two-block unpack inverts both, (c) a
-    two-block tile's bytes are exactly its blocks' bytes concatenated —
-    the property that makes the tile a pure kernel granularity choice
-    rather than a layout migration.
+    (a) shift-or pack == scatter pack, (b) the kernels' plane unpack
+    (``decode_lib.unpack_planes`` over the packed bytes split into byte
+    planes, ``Bg`` bytes to a code group) inverts both, (c) a two-block
+    run of codes packs to exactly its blocks' bytes concatenated — groups
+    and kernel tiles are a granularity choice, never a layout migration.
     """
-    from repro.core.pack import pack_codes_scatter, pack_tile
-    from repro.kernels.decode_lib import unpack_codes_pallas
+    from repro.core.pack import pack_codes_scatter
+    from repro.kernels.decode_lib import code_group, unpack_planes
     r = np.random.default_rng(seed)
     nb = 2 * npairs
     codes = r.integers(0, 2 ** bits, size=(3, nb, 32)).astype(np.uint8)
@@ -186,10 +186,13 @@ def test_two_block_tile_pack_roundtrip(bits, npairs, seed):
     np.testing.assert_array_equal(
         np.asarray(packed),
         np.asarray(pack_codes_scatter(jnp.asarray(codes), bits)))
-    out = unpack_codes_pallas(packed, bits)
-    np.testing.assert_array_equal(np.asarray(out), codes.astype(np.int32))
-    n_codes, n_bytes = pack_tile(bits)
-    assert (n_codes, n_bytes) == (64, 8 * bits)
+    p_n, bg = code_group(bits)
+    groups = np.asarray(packed).astype(np.int32).reshape(3, -1, bg)
+    planes = unpack_planes([jnp.asarray(groups[..., q]) for q in range(bg)],
+                           bits)
+    assert len(planes) == p_n
+    out = np.stack([np.asarray(c) for c in planes], -1).reshape(3, nb, 32)
+    np.testing.assert_array_equal(out, codes.astype(np.int32))
     tiled = pack_codes(jnp.asarray(codes.reshape(3, npairs, 64)), bits)
     np.testing.assert_array_equal(
         np.asarray(tiled).reshape(3, nb, 4 * bits), np.asarray(packed))

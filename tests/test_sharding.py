@@ -59,9 +59,9 @@ def test_shard_friendly_kv_replication():
 
 
 _SUBPROC = r"""
-import jax
 from repro.launch.dryrun import lower_cell
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 r = lower_cell("llama3_8b", "decode_32k", mesh)
 assert r["cost"].get("flops", 0) > 0
 colls = {k: v["count"] for k, v in r["collectives"].items() if v["count"]}
