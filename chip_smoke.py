@@ -73,12 +73,7 @@ def make_requests(cfg, n: int, seed: int):
 def build_params(cfg, seed: int, mesh=None):
     t0 = time.perf_counter()
     params = init_cast_params(cfg, jax.random.PRNGKey(seed), POLICY,
-                              quantize_fn=ops.quantize_qtensor, mesh=mesh)
-    # the model computes in bf16 and casts the dense embedding and head
-    # to it on every call: stored in bf16 they serve the same values, and
-    # the decode program no longer keeps hoisted bf16 copies (2 GiB)
-    for name in ("tok_embed", "lm_head"):
-        params[name] = params[name].astype(cfg.dtype)
+                              mesh=mesh)
     jax.block_until_ready(params)
     layers = tree_footprint_bytes(params["layers"])
     n_layer = cfg.param_count() - 2 * cfg.vocab * cfg.d_model
@@ -144,6 +139,38 @@ def decode_custom_calls(eng) -> int:
     return hlo.count("tpu_custom_call")
 
 
+def time_warm(fn, n: int = 5):
+    """Sorted wall seconds of ``n`` warm calls of ``fn``, each waited on."""
+    jax.block_until_ready(fn())
+    out = []
+    for _ in range(n):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn())
+        out.append(time.perf_counter() - t0)
+    return sorted(out)
+
+
+def time_programs(eng) -> None:
+    """The two programs a serve loop dispatches, each alone and warm, on
+    the engine's own params and cache (results discarded).  Both kernels
+    read every context row whatever the slot lengths, so an idle batch
+    costs what a full one does."""
+    args = eng.chunk_args(np.zeros((eng.n_slots,), bool))
+    t = time_warm(lambda: eng._chunk_jit(eng.params, *args,
+                                         n_steps=eng.chunk, greedy=True))
+    log(f"decode chunk alone ({eng.n_slots} rows x {eng.chunk} steps, "
+        f"{MAX_LEN} context rows): median {t[2] * 1e3:.1f} ms = "
+        f"{t[2] / eng.chunk * 1e3:.2f} ms per step, 5 calls "
+        f"{t[0] * 1e3:.1f}-{t[-1] * 1e3:.1f} ms (this run)")
+    toks = jnp.zeros((1, P_CHUNK), jnp.int32)
+    t = time_warm(lambda: eng._lane_fn(
+        eng.params, toks, eng.cache, eng.lane, jnp.int32(0), jnp.int32(0),
+        jnp.int32(P_CHUNK), with_head=False, wrapped=False))
+    log(f"prefill-lane chunk alone (1 x {P_CHUNK} tokens, no head): median "
+        f"{t[2] * 1e3:.1f} ms, 5 calls {t[0] * 1e3:.1f}-{t[-1] * 1e3:.1f} "
+        f"ms (this run)")
+
+
 def engine(cfg, params, n_slots: int, mesh=None):
     kw = dict(n_slots=n_slots, max_len=MAX_LEN, prefill_mode="chunked",
               p_chunk=P_CHUNK)
@@ -175,6 +202,7 @@ def one_chip(cfg, seed: int) -> None:
     peak = mem(jax.devices()[0], "peak_bytes_in_use")
     log(f"KV cache + slot state: {kv / GiB:.3f} GiB; peak_bytes_in_use "
         f"{peak / GiB:.3f} GiB")
+    time_programs(eng)
 
 
 def four_chips(cfg, seed: int, n_chips: int) -> None:

@@ -75,11 +75,21 @@ def _kernel_ok(fmt: BlockFormat) -> bool:
     return fmt.bits in _KERNEL_BITS and _arith_ok(fmt)
 
 
+# Largest quantized tile a dequant kernel step may decode, in elements
+# (weight TK x TN, activation TK x TM, cache D x TS).  A step holds it as
+# an int32 byte view plus f32 and bf16 planes, ~8 bytes an element at
+# 4-bit, so the largest preferred tile (1024 x 512) takes ~4 MiB of v5e's
+# 16 MiB scoped VMEM.  Shapes whose only legal tile is a larger whole dim
+# (no preferred tile divides them, e.g. hymba-1.5b's 5504 x 1600 down
+# projection) take the XLA path (DESIGN.md §2.5).
+_MAX_TILE_ELEMS = 1024 * 512
+
+
 def _tile(dim: int, prefs) -> int:
     """Largest preferred tile dividing ``dim``, else the whole dim.
 
     Every preference keeps the kernel blocks (8, 128)-aligned; a whole
-    dim is always a legal block."""
+    dim is always a legal block, but may exceed ``_MAX_TILE_ELEMS``."""
     return next((t for t in prefs if dim % t == 0), dim)
 
 
@@ -133,12 +143,13 @@ def qmatmul(x, w, impl: Optional[str] = None):
     if x2.shape[-1] < k_pad:  # quantization padded K to a block multiple
         x2 = jnp.pad(x2, ((0, 0), (0, k_pad - x2.shape[-1])))
 
-    if impl == "pallas" and _kernel_ok(w.fmt):
+    tn = _tile(n, (512, 256, 128))
+    tk = _tile_k(k_pad, code_group(w.fmt.bits)[0], True)
+    if (impl == "pallas" and _kernel_ok(w.fmt)
+            and tk * tn <= _MAX_TILE_ELEMS):
         y = nxfp_matmul_pallas(
             x2, w.packed, w.meta, w.fmt, tile_m=_tile_m(x2.shape[0]),
-            tile_n=_tile(n, (512, 256, 128)),
-            tile_k=_tile_k(k_pad, code_group(w.fmt.bits)[0], True),
-            interpret=_interpret())
+            tile_n=tn, tile_k=tk, interpret=_interpret())
         return y.reshape(*lead, n)
     y = kref.qmatmul_ref(x2, w.packed, w.meta, w.fmt)
     return y.reshape(*lead, n)
@@ -163,11 +174,13 @@ def _qact_matmul(xq: QTensor, w, impl: Optional[str]):
     n = w.packed.shape[0]
     k_pad = kb * x_fmt.block_size
 
-    if impl == "pallas" and _kernel_ok(x_fmt) and _kernel_ok(w_fmt):
+    tn = _tile(n, (512, 256, 128))
+    tk = _tile_k(k_pad, qq_planes(x_fmt, w_fmt)[0], False)
+    if (impl == "pallas" and _kernel_ok(x_fmt) and _kernel_ok(w_fmt)
+            and tk * max(tn, _tile_m(xp.shape[0])) <= _MAX_TILE_ELEMS):
         y = nxfp_qq_matmul_pallas(
             xp, xm, w.packed, w.meta, x_fmt, w_fmt,
-            tile_m=_tile_m(xp.shape[0]), tile_n=_tile(n, (512, 256, 128)),
-            tile_k=_tile_k(k_pad, qq_planes(x_fmt, w_fmt)[0], False),
+            tile_m=_tile_m(xp.shape[0]), tile_n=tn, tile_k=tk,
             interpret=_interpret())
         return y.reshape(*lead, n)
     y = kref.qq_matmul_ref(xp, xm, x_fmt, w.packed, w.meta, w_fmt)
@@ -234,11 +247,11 @@ def decode_attention(q, kq: QTensor, vq: QTensor, lengths, n_kv_heads: int,
     d_pad = kq.packed.shape[-2] * fmt.block_size
     if d_pad != d:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, d_pad - d)))
-    if impl == "pallas" and _kernel_ok(fmt):
+    ts = _tile(kq.packed.shape[1], (512, 256, 128))
+    if impl == "pallas" and _kernel_ok(fmt) and d_pad * ts <= _MAX_TILE_ELEMS:
         out = nxfp_decode_attention_pallas(
             qg, kq.packed, kq.meta, vq.packed, vq.meta, lengths2, fmt,
-            tile_s=_tile(kq.packed.shape[1], (512, 256, 128)),
-            interpret=_interpret())
+            tile_s=ts, interpret=_interpret())
     else:
         out = kref.decode_attention_ref(
             qg, kq.packed, kq.meta, vq.packed, vq.meta, lengths2, fmt)

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.qtensor import QTensor, QuantPolicy, direct_cast_tree
+from repro.kernels.ops import quantize_qtensor
 
 from .attention import memory_kv
 from .blocks import (init_layer, layer_decode, layer_forward,
@@ -64,19 +65,23 @@ def init_params(cfg: ModelConfig, key) -> Params:
 
 
 def init_cast_params(cfg: ModelConfig, key, policy: QuantPolicy,
-                     quantize_fn=None, mesh=None) -> Params:
-    """``direct_cast_tree(init_params(cfg, key), policy, quantize_fn)``,
+                     mesh=None) -> Params:
+    """``direct_cast_tree(init_params(cfg, key), policy, quantize_qtensor)``,
     built one layer at a time so the full float32 tree never exists.
 
     Each layer is initialized from its own key of ``init_params``' layer
     split, cast, and written into a preallocated stacked buffer in place;
     the top-level leaves (embeddings, head, norms) come from the same
     stream.  The result is bit-identical to casting the full tree under
-    ``jit``, at a peak of the cast tree plus one float32 layer.  With a
-    ``mesh`` every leaf is replicated over it (the serving engines'
-    weight layout) and each device casts its own replica; None leaves
-    them on the default device.  Families with one homogeneous layer
-    stack only.
+    ``jit``, at a peak of the cast tree plus one float32 layer, except
+    that a dense token embedding and head are stored in ``cfg.dtype``:
+    the model reads them only in that dtype (``_embed``, and the bf16
+    operands of ``qmatmul``), so they serve the same values, and the
+    step programs keep no converted copies of them (2 GiB at llama3-8b).
+    With a ``mesh`` every leaf is replicated over it (the serving
+    engines' weight layout) and each device casts its own replica; None
+    leaves them on the default device.  Families with one homogeneous
+    layer stack only.
     """
     if cfg.family not in _KIND:
         raise ValueError(f"layer-wise cast init supports {sorted(_KIND)}, "
@@ -87,22 +92,25 @@ def init_cast_params(cfg: ModelConfig, key, policy: QuantPolicy,
                          "pos"])["layers"], cfg.n_layers)
 
     def cast(tree):
-        return direct_cast_tree(tree, policy, quantize_fn)
+        return direct_cast_tree(tree, policy, quantize_qtensor)
 
     def top():
         p = init_params(cfg, key)
         p.pop("layers")                 # dead code: XLA never builds it
-        return cast(p)
+        p = cast(p)
+        for name in ("tok_embed", "lm_head"):
+            if not isinstance(p[name], QTensor):
+                p[name] = p[name].astype(cfg.dtype)
+        return p
 
     # which stacked leaves the policy casts is decided on the stacked
     # shapes (min_size, ndim), exactly as for the full tree
     ref = jax.eval_shape(lambda: cast(init_params(cfg, key)))["layers"]
-    qfn = quantize_fn or (lambda x, f, a: QTensor.quantize(x, f, axis=a))
 
     def one_layer(k):
         layer = init_layer(k, cfg, kind)
         return jax.tree.leaves({
-            n: (qfn(v, policy.weight_fmt, policy.axis)
+            n: (quantize_qtensor(v, policy.weight_fmt, policy.axis)
                 if isinstance(ref[n], QTensor) else v)
             for n, v in layer.items()})
 

@@ -250,7 +250,8 @@ def test_serve_engine_per_slot_vectors_host_device_identical():
 
 def test_engine_serves_precast_params_identically():
     """Weights cast one layer at a time (``init_cast_params``) equal the
-    whole-tree cast bit for bit, the engine leaves them as they are
+    whole-tree cast bit for bit, the dense embedding and head stored in
+    the model's compute dtype; the engine leaves them as they are
     (casting a cast tree is the identity), and greedy streams match an
     engine handed the raw float32 tree."""
     from repro.core.qtensor import QTensor, direct_cast_tree
@@ -261,9 +262,12 @@ def test_engine_serves_precast_params_identically():
     key = jax.random.PRNGKey(0)
     whole = jax.jit(lambda: direct_cast_tree(
         init_params(cfg, key), policy, quantize_qtensor))()
-    cast = init_cast_params(cfg, key, policy, quantize_fn=quantize_qtensor)
+    for name in ("tok_embed", "lm_head"):
+        whole[name] = whole[name].astype(cfg.dtype)
+    cast = init_cast_params(cfg, key, policy)
     again = direct_cast_tree(cast, policy, quantize_qtensor)
     assert isinstance(cast["layers"]["wq"], QTensor)
+    assert cast["lm_head"].dtype == cfg.dtype
     for other in (whole, again):
         assert jax.tree.structure(other) == jax.tree.structure(cast)
         for a, b in zip(jax.tree.leaves(other), jax.tree.leaves(cast)):

@@ -43,9 +43,9 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(ops, "_interpret", lambda: False)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernels: bool = True):
     hlo = jax.jit(fn).lower(*args).compile().as_text()
-    assert hlo.count("tpu_custom_call") >= 1
+    assert (hlo.count("tpu_custom_call") >= 1) == kernels
     return hlo
 
 
@@ -68,11 +68,24 @@ def _weight(sharding, fname, k, n):
     ("nxfp5", 8, 4096, 4096),
     ("nxfp6", 8, 4096, 4096),
     ("nxfp8", 8, 4096, 4096),
+    ("nxfp4", 8, 1600, 5504),       # hymba-1.5b up projection: whole-K tile
 ])
 def test_nxfp_matmul_compiles(one_chip, mosaic, fname, m, k, n):
     _compile(lambda x, w: ops.qmatmul(x, w, impl="pallas"),
              _sds(one_chip, (m, k), jnp.bfloat16),
              _weight(one_chip, fname, k, n))
+
+
+def test_untileable_width_takes_xla_path(one_chip, mosaic):
+    """hymba-1.5b's down projection (K=5504, N=1600): no preferred tile
+    divides either dim, the whole-dim tile is over ``_MAX_TILE_ELEMS``,
+    and the GEMM compiles on the XLA path with no kernel in it."""
+    k, n = 5504, 1600
+    assert (ops._tile_k(k, 2, True) * ops._tile(n, (512, 256, 128))
+            > ops._MAX_TILE_ELEMS)
+    _compile(lambda x, w: ops.qmatmul(x, w, impl="pallas"),
+             _sds(one_chip, (8, k), jnp.bfloat16),
+             _weight(one_chip, "nxfp4", k, n), kernels=False)
 
 
 @pytest.mark.parametrize("fname", ["nxfp4", "nxfp6"])
