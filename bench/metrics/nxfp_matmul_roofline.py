@@ -1,0 +1,6 @@
+"""Roofline share of the nxfp_matmul kernel calls traced (%)."""
+from bench import measures
+
+
+def read(ctx):
+    return measures.matmul_roofline(ctx)

@@ -1,0 +1,5 @@
+"""``peak_bytes_in_use`` of the fullest device after the window (GiB)."""
+
+
+def read(ctx):
+    return ctx.peak_bytes / 2 ** 30 if ctx.peak_bytes else None
