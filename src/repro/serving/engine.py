@@ -78,6 +78,16 @@ def cached_program(key, build):
     return fn
 
 
+def named_jit(name: str, fn, **jit_kwargs):
+    """``jax.jit(fn, **jit_kwargs)`` whose program is named ``jit_<name>``
+    in the HLO, the profiler trace and compile logs -- a jitted
+    ``functools.partial`` is otherwise ``jit__unknown``, and a closure
+    takes its own name."""
+    fn = functools.partial(fn)
+    fn.__name__ = name
+    return jax.jit(fn, **jit_kwargs)
+
+
 # servers capture/silence straggler + scheduler telemetry through the
 # standard logging tree ("repro.serving" / "repro.serving.scheduler") —
 # no bare prints on the serving path
@@ -141,11 +151,13 @@ class ServeEngine:
         kv = policy.kv_fmt
         self._prefill = cached_program(
             ("serve_prefill", cfg, kv, max_len),
-            lambda: jax.jit(
+            lambda: named_jit(
+                "serve_prefill",
                 lambda p, b: prefill(cfg, p, b, max_len=max_len, kv_fmt=kv)))
         self._decode = cached_program(
             ("serve_decode", cfg, kv),
-            lambda: jax.jit(
+            lambda: named_jit(
+                "serve_decode",
                 lambda p, t, c: decode_step(cfg, p, t, c, kv_fmt=kv)))
         # temperature/stop are traced PER-SLOT (B,) vectors (greedy-ness is
         # the only sampling branch), so one batch serves mixed per-request
@@ -153,7 +165,8 @@ class ServeEngine:
         # length does
         self._chunk = cached_program(
             ("serve_chunk", cfg, kv),
-            lambda: jax.jit(
+            lambda: named_jit(
+                "serve_chunk",
                 functools.partial(self._chunk_fn, cfg=cfg, kv_fmt=kv),
                 static_argnames=("n_steps", "greedy")))
         self._key = jax.random.PRNGKey(rng_seed)

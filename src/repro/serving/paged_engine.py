@@ -54,7 +54,7 @@ from repro.core.qtensor import QuantPolicy
 from repro.models.common import ModelConfig, gated_update_slice
 from repro.models.lm import init_paged_cache
 from repro.sharding import shard_map_manual
-from .engine import cached_program
+from .engine import cached_program, named_jit
 from .paged import NULL_PAGE, PagePool, auto_page_size
 from .scheduler import ContinuousEngine, Request, SlotScheduler
 from .sharded import _R, ShardedContinuousEngine, _owner_apply
@@ -282,10 +282,12 @@ class PagedContinuousEngine(ContinuousEngine):
     def _build_paged_programs(self) -> None:
         cfg, kv, mk = self.cfg, self._kv, self._mesh_key
         key = (cfg, kv, mk, self.n_pages, self.page_size)
-        self._table = cached_program(("paged_table",) + key,
-                                     lambda: jax.jit(_table_write))
-        self._copy_page = cached_program(("paged_copy",) + key,
-                                         lambda: jax.jit(_copy_page_fn))
+        self._table = cached_program(
+            ("paged_table",) + key, lambda: named_jit("paged_table",
+                                                      _table_write))
+        self._copy_page = cached_program(
+            ("paged_copy",) + key, lambda: named_jit("paged_copy",
+                                                     _copy_page_fn))
 
     def _make_sched(self) -> SlotScheduler:
         sched = super()._make_sched()
@@ -467,7 +469,7 @@ class ShardedPagedContinuousEngine(PagedContinuousEngine,
         self._table = cached_program(
             ("paged_table", cfg, kv, mk, nloc, self.n_pages,
              self.page_size),
-            lambda: jax.jit(shard_map_manual(
+            lambda: named_jit("paged_table", shard_map_manual(
                 table_body, mesh, in_specs=(cspec, _R, _R),
                 out_specs=cspec)))
         # no COW copy program: prefix sharing (the only writer of shared

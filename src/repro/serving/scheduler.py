@@ -66,8 +66,8 @@ from repro.models import (decode_loop, init_cache, init_lane, prefill_chunk,
                           write_cache_slot)
 from repro.models.common import ModelConfig, gated_update_slice
 from repro.models.kvcache import kv_slot_checksum, ssm_state_checksum
-from .engine import cached_program, mask_chunk_emissions
-from .events import Journal, replay
+from .engine import cached_program, mask_chunk_emissions, named_jit
+from .events import Journal, Loop, replay
 from .faults import flip_kv_bytes
 from .snapshot import (SlotSnapshot, load_checkpoint, pack_device_state,
                        save_checkpoint, slot_row_capacity,
@@ -808,6 +808,7 @@ class ContinuousEngine:
         self.shedding = shedding
         self.preemption = preemption
         self.journal = Journal()
+        self._loop = Loop(logger)           # serve-loop spans and records
         self._cancel_uids: set = set()
         self._suspend_uids: set = set()
         self._fault_plan = None
@@ -908,51 +909,56 @@ class ContinuousEngine:
         cfg, kv, max_len, mk = self.cfg, self._kv, self.max_len, self._mesh_key
         self._prefill = cached_program(
             ("admit", cfg, kv, max_len, mk),
-            lambda: jax.jit(functools.partial(
+            lambda: named_jit("admit", functools.partial(
                 self._admit_fn, cfg=cfg, kv_fmt=kv, max_len=max_len)))
         self._reset = cached_program(
             ("reset", cfg, mk),
-            lambda: jax.jit(functools.partial(reset_slot, cfg)))
+            lambda: named_jit("reset_slot",
+                              functools.partial(reset_slot, cfg)))
         self._chunk_jit = cached_program(
             ("cont_chunk", cfg, kv, mk),
-            lambda: jax.jit(
+            lambda: named_jit(
+                "decode_chunk",
                 functools.partial(self._chunk_fn, cfg=cfg, kv_fmt=kv),
                 static_argnames=("n_steps", "greedy")))
         if self.speculative is not None:
             self._spec_jit = cached_program(
                 ("spec_chunk", cfg, kv, mk),
-                lambda: jax.jit(
+                lambda: named_jit(
+                    "spec_chunk",
                     functools.partial(self._spec_chunk_fn, cfg=cfg,
                                       kv_fmt=kv),
                     static_argnames=("k", "n_rounds", "greedy")))
         # snapshot extract/restore: one fixed-shape program each (slot is
         # a traced index), shared by suspend, migration and checkpoint
         self._snap = cached_program(
-            ("snap", cfg, kv, mk), lambda: jax.jit(read_cache_slot))
+            ("snap", cfg, kv, mk), lambda: named_jit("snap", read_cache_slot))
         self._restore_prog = cached_program(
-            ("restore", cfg, kv, mk), lambda: jax.jit(write_cache_slot))
+            ("restore", cfg, kv, mk),
+            lambda: named_jit("restore", write_cache_slot))
         if self.kv_integrity:
             if self._has_attn_kv:
                 self._kv_check = cached_program(
                     ("kv_check", cfg, kv, mk),
-                    lambda: jax.jit(functools.partial(kv_slot_checksum,
-                                                      cfg)))
+                    lambda: named_jit("kv_check", functools.partial(
+                        kv_slot_checksum, cfg)))
             if self._has_ssm:
                 self._ssm_check = cached_program(
                     ("ssm_check", cfg, mk),
-                    lambda: jax.jit(functools.partial(ssm_state_checksum,
-                                                      cfg)))
+                    lambda: named_jit("ssm_check", functools.partial(
+                        ssm_state_checksum, cfg)))
 
     def _build_lane(self) -> None:
         cfg, kv, mk = self.cfg, self._kv, self._mesh_key
         self.lane = init_lane(cfg, self.max_len, self.p_chunk)
         self._lane_fn = cached_program(
             ("lane", cfg, kv, self.p_chunk, mk),
-            lambda: jax.jit(functools.partial(
+            lambda: named_jit("lane_chunk", functools.partial(
                 self._lane_chunk_fn, cfg=cfg, kv_fmt=kv),
                 static_argnames=("with_head", "wrapped")))
         self._finish = cached_program(
-            ("finish", cfg, mk), lambda: jax.jit(self._finish_prefill_fn))
+            ("finish", cfg, mk),
+            lambda: named_jit("lane_finish", self._finish_prefill_fn))
 
     # -- p_chunk autotuning (ROADMAP follow-up) -----------------------------
 
@@ -1024,7 +1030,7 @@ class ContinuousEngine:
             # transfers even though its fused program is keyed apart
             fn = cached_program(
                 ("lane", cfg, kv, p, None),
-                lambda: jax.jit(functools.partial(
+                lambda: named_jit("lane_chunk", functools.partial(
                     self._lane_chunk_fn, cfg=cfg, kv_fmt=kv),
                     static_argnames=("with_head", "wrapped")))
             toks = np.zeros((1, p), np.int32)
@@ -1234,7 +1240,8 @@ class ContinuousEngine:
 
     def _arm_slot(self, slot: int, req: Request, tok0, key) -> None:
         """Host-side slot state for a freshly admitted, decoding request."""
-        self._tok[slot] = int(tok0)
+        with self._loop.span("serve.lane_wait"):
+            self._tok[slot] = int(tok0)
         self._keys[slot] = np.asarray(key, np.uint32)
         self._done[slot] = False
         self._live[slot] = True
@@ -1273,8 +1280,11 @@ class ContinuousEngine:
             self._seen_prompt_lens.add(t)
             logger.info("first prompt of length %d: compiling prefill "
                         "(bucket prompt lengths to bound compiles)", t)
-        tok0, key = self._admit_dispatch(slot, req)
-        self._arm_slot(slot, req, tok0, key)
+        with self._loop.span("serve.lane", uid=req.uid, offset=0,
+                             n_valid=t, final=True):
+            tok0, key = self._admit_dispatch(slot, req)
+            self._loop.add(lane_tokens=t)
+            self._arm_slot(slot, req, tok0, key)
         admit_done = clock()
         self._emit("admit", uid=req.uid, slot=slot,
                    shard=self._shard_of(slot), prompt=t, max_new=req.max_new,
@@ -1402,28 +1412,51 @@ class ContinuousEngine:
         t = len(req.tokens)
         n_valid = min(self.p_chunk, t - off)
         final = off + n_valid >= t
-        chunk_toks = np.zeros((1, self.p_chunk), np.int32)
-        chunk_toks[0, :n_valid] = req.tokens[off:off + n_valid]
+        with self._loop.span("serve.lane", uid=req.uid, offset=off,
+                             n_valid=n_valid, final=final):
+            chunk_toks = np.zeros((1, self.p_chunk), np.int32)
+            chunk_toks[0, :n_valid] = req.tokens[off:off + n_valid]
+            logits = self._lane_dispatch(req, chunk_toks, slot, off,
+                                         n_valid, final)
+            self._loop.add(lane_tokens=n_valid)
+            pf["offset"] = off + n_valid
+            if not final:
+                return
+            tok0, key = self._finish_dispatch(logits, req, slot)
+            self._arm_slot(slot, req, tok0, key)
+            sched.mark_decoding(slot)
+            state[slot] = {"admit_time": pf["admit_time"], "out": [],
+                           "prev_n_gen": 0,
+                           "queue_delay": pf["admit_time"] - req.arrival_time,
+                           "ttft": clock() - req.arrival_time,
+                           "decode_spent": 0.0}
+            self._emit("prefill-done", uid=req.uid, slot=slot, prompt=t,
+                       ttft=state[slot]["ttft"])
+            self._pf = None
+
+    def _lane_dispatch(self, req: Request, toks, slot: int, off: int,
+                       n_valid: int, final: bool):
+        """Run one lane chunk of ``req`` (``toks`` (1, p_chunk), its first
+        ``n_valid`` from prompt offset ``off``) into ``slot``; returns the
+        chunk's logits (the head's only on the ``final`` chunk)."""
         logits, self.cache, self.lane = self._lane_fn(
-            self.params, chunk_toks, self.cache, self.lane,
-            jnp.int32(slot), jnp.int32(off), jnp.int32(n_valid),
-            with_head=final, wrapped=off >= self._lane_rows)
-        pf["offset"] = off + n_valid
-        if not final:
-            return
-        tok0, key, self.cache = self._finish(
-            logits, jax.random.PRNGKey(req.seed),
-            jnp.float32(req.temperature), self.cache, jnp.int32(slot), t)
-        self._arm_slot(slot, req, tok0, key)
-        sched.mark_decoding(slot)
-        state[slot] = {"admit_time": pf["admit_time"], "out": [],
-                       "prev_n_gen": 0,
-                       "queue_delay": pf["admit_time"] - req.arrival_time,
-                       "ttft": clock() - req.arrival_time,
-                       "decode_spent": 0.0}
-        self._emit("prefill-done", uid=req.uid, slot=slot, prompt=t,
-                   ttft=state[slot]["ttft"])
-        self._pf = None
+            self.params, toks, self.cache, self.lane, jnp.int32(slot),
+            jnp.int32(off), jnp.int32(n_valid), with_head=final,
+            wrapped=off >= self._lane_rows)
+        return logits
+
+    def _finish_dispatch(self, logits, req: Request, slot: int):
+        """The final lane chunk's tail: sample ``req``'s first token and
+        arm ``slot`` on the device; returns (tok0, key), on the device.
+        The program's call is part of the first-token wait: where the
+        device's memory is full, its outputs are allocated only once the
+        lane chunk's buffers are freed."""
+        key = jax.random.PRNGKey(req.seed)
+        temp, at = jnp.float32(req.temperature), jnp.int32(slot)
+        with self._loop.span("serve.lane_wait"):
+            tok0, key, self.cache = self._finish(
+                logits, key, temp, self.cache, at, len(req.tokens))
+        return tok0, key
 
     # -- request lifecycle: cancellation, deadlines, shedding, quarantine ----
 
@@ -2023,41 +2056,67 @@ class ContinuousEngine:
         ``n_rounds * (k+1)``), which the harvest loop never notices: it
         reads each slot's ``n_gen`` delta off the packed prefix.
         """
-        args = self.chunk_args(poison)
+        loop = self._loop
+        with loop.span("serve.upload"):
+            args = self.chunk_args(poison)
         greedy = bool((self._temp == 0.0).all())
-        if self.speculative is None:
-            (emitted, tok, self.cache, keys, done, n_gen,
-             finite) = self._chunk_jit(self.params, *args,
-                                       n_steps=self.chunk, greedy=greedy)
-            acc = off = None
-        else:
-            k, n_rounds = self._spec_round_shape()
-            (emitted, tok, self.cache, keys, done, n_gen, finite, acc,
-             off) = self._spec_jit(self.params, self.draft_params, *args,
-                                   jnp.asarray(self._adaptive.k), k=k,
-                                   n_rounds=n_rounds, greedy=greedy)
+        with loop.span("serve.dispatch"):
+            if self.speculative is None:
+                (emitted, tok, self.cache, keys, done, n_gen,
+                 finite) = self._chunk_jit(self.params, *args,
+                                           n_steps=self.chunk,
+                                           greedy=greedy)
+                acc = off = None
+            else:
+                k, n_rounds = self._spec_round_shape()
+                (emitted, tok, self.cache, keys, done, n_gen, finite, acc,
+                 off) = self._spec_jit(self.params, self.draft_params,
+                                       *args, jnp.asarray(self._adaptive.k),
+                                       k=k, n_rounds=n_rounds,
+                                       greedy=greedy)
         # one host transfer per chunk; copies (not views) because the
         # admission path mutates these slotwise between chunks
-        got = jax.device_get((emitted, tok, keys, done, n_gen, finite)
-                             + (() if acc is None else (acc, off)))
-        emitted, tok, keys, done, n_gen, finite = got[:6]
-        self._tok = np.array(tok)
-        self._keys = np.array(keys, np.uint32)
-        self._done = np.array(done)
-        self._n_gen = np.array(n_gen)
-        if acc is not None:
-            acc, off = np.asarray(got[6]), np.asarray(got[7])
-            self.spec_accepted += int(acc.sum())
-            self.spec_offered += int(off.sum())
-            self._spec_acc_slot += acc.astype(np.int64)
-            self._spec_off_slot += off.astype(np.int64)
-            old_k = self._adaptive.k.copy()
-            self._adaptive.update(self._live, acc, off)
-            for s in np.nonzero(self._adaptive.k != old_k)[0]:
-                self._emit("spec-k", slot=int(s), k=int(self._adaptive.k[s]),
-                           ema=round(float(self._adaptive.ema[s]), 3),
-                           chunk=self._chunk_idx)
+        with loop.span("serve.wait"):
+            got = jax.device_get((emitted, tok, keys, done, n_gen, finite)
+                                 + (() if acc is None else (acc, off)))
+        with loop.span("serve.harvest"):
+            emitted, tok, keys, done, n_gen, finite = got[:6]
+            self._tok = np.array(tok)
+            self._keys = np.array(keys, np.uint32)
+            self._done = np.array(done)
+            self._n_gen = np.array(n_gen)
+            if acc is not None:
+                acc, off = np.asarray(got[6]), np.asarray(got[7])
+                self.spec_accepted += int(acc.sum())
+                self.spec_offered += int(off.sum())
+                self._spec_acc_slot += acc.astype(np.int64)
+                self._spec_off_slot += off.astype(np.int64)
+                old_k = self._adaptive.k.copy()
+                self._adaptive.update(self._live, acc, off)
+                for s in np.nonzero(self._adaptive.k != old_k)[0]:
+                    self._emit("spec-k", slot=int(s),
+                               k=int(self._adaptive.k[s]),
+                               ema=round(float(self._adaptive.ema[s]), 3),
+                               chunk=self._chunk_idx)
         return emitted, np.asarray(finite)
+
+    def _count_chunk(self, sched: SlotScheduler) -> None:
+        """The next decode chunk's counters for the iteration record:
+        the slots that decode in it, its steps (a speculative chunk's
+        rows written per slot), and the valid K/V rows its attention
+        reads -- at step j (1..steps) a slot reads its position (prompt
+        plus tokens generated) plus j rows, at most the sliding window."""
+        live = np.nonzero(self._live & ~self._done)[0]
+        steps = self._chunk_horizon()
+        rows = 0
+        if self._has_attn_kv and live.size:
+            pos = self._n_gen[live] + np.array(
+                [len(sched.active[int(s)].tokens) for s in live])
+            read = pos[:, None] + np.arange(1, steps + 1)
+            if self.cfg.sliding_window:
+                read = np.minimum(read, self.cfg.sliding_window)
+            rows = int(read.sum())
+        self._loop.add(live=live.size, steps=steps, rows=rows)
 
     def spec_stats(self) -> Dict[str, Any]:
         """Aggregate speculative acceptance counters (benches read this)."""
@@ -2149,64 +2208,82 @@ class ContinuousEngine:
         # (checkpoint(), snapshot_slot(), drain sweeps)
         self._sched, self._state = sched, state
         self._results, self._clock = results, clock
+        # each pass of the loop is one iteration: phase spans and, at INFO,
+        # one ``iteration`` record before ``progress_cb`` (events.Loop)
+        loop = self._loop
+        loop.reset()
 
         while True:
-            self._lifecycle(sched, state, results, clock)
-            if not sched.has_work:
+            loop.begin()
+            with loop.span("serve.lifecycle"):
+                self._lifecycle(sched, state, results, clock)
+                work = sched.has_work
+                if work:
+                    self._preempt_sweep(sched, state, clock)
+                    self._resume_ready(sched, state, clock)
+            if not work:
+                loop.end()
                 break
-            self._preempt_sweep(sched, state, clock)
-            self._resume_ready(sched, state, clock)
             now = clock()
             if chunked:
                 self._advance_lane(sched, state, clock)
             else:
                 self._admit_ready(sched, state, now, clock)
             if not self._live.any():
-                if chunked and self._lane_busy():
-                    continue            # lane keeps grinding, no decoders
-                nxt = sched.next_arrival()
-                assert nxt is not None
-                time.sleep(max(nxt - clock(), 0.0))
+                if not (chunked and self._lane_busy()):
+                    # nothing decodes and the lane is idle: sleep to the
+                    # next arrival (else the lane keeps grinding)
+                    nxt = sched.next_arrival()
+                    assert nxt is not None
+                    with loop.span("serve.sleep"):
+                        time.sleep(max(nxt - clock(), 0.0))
+                loop.end()
                 continue
 
             if self.kv_integrity:
                 self._kv_refresh()
             poison = self._inject_faults(sched)
+            if loop.recording:
+                self._count_chunk(sched)
             emitted, finite = self._dispatch_chunk(poison)
             self._chunk_idx += 1
             now = clock()
 
-            # containment: sentinel (always) + KV canaries (opt-in), then
-            # quarantine BEFORE harvest so a faulted chunk's tokens are
-            # discarded rather than delivered
-            bad = ~np.asarray(finite) & self._live
-            cause = {int(s): "nan_logits" for s in np.nonzero(bad)[0]}
-            if self.kv_integrity:
-                kv_bad = self._kv_verify() & self._live
-                for s in np.nonzero(kv_bad & ~bad)[0]:
-                    cause[int(s)] = "kv_integrity"
-                bad = bad | kv_bad
-                # SSM at-rest trip (computed pre-chunk in _kv_refresh):
-                # the idle-window corruption poisoned THIS chunk's scan
-                ssm_bad = self._ssm_bad & self._live
-                for s in np.nonzero(ssm_bad & ~bad)[0]:
-                    cause[int(s)] = "ssm_integrity"
-                bad = bad | ssm_bad
-            if bad.any():
-                self._quarantine(sched, state, results, bad, cause, clock)
+            with loop.span("serve.harvest"):
+                # containment: sentinel (always) + KV canaries (opt-in),
+                # then quarantine BEFORE harvest so a faulted chunk's
+                # tokens are discarded rather than delivered
+                bad = ~np.asarray(finite) & self._live
+                cause = {int(s): "nan_logits" for s in np.nonzero(bad)[0]}
+                if self.kv_integrity:
+                    kv_bad = self._kv_verify() & self._live
+                    for s in np.nonzero(kv_bad & ~bad)[0]:
+                        cause[int(s)] = "kv_integrity"
+                    bad = bad | kv_bad
+                    # SSM at-rest trip (computed pre-chunk in
+                    # _kv_refresh): the idle-window corruption poisoned
+                    # THIS chunk's scan
+                    ssm_bad = self._ssm_bad & self._live
+                    for s in np.nonzero(ssm_bad & ~bad)[0]:
+                        cause[int(s)] = "ssm_integrity"
+                    bad = bad | ssm_bad
+                if bad.any():
+                    self._quarantine(sched, state, results, bad, cause,
+                                     clock)
 
-            for slot in list(sched.active):
-                st = state.get(slot)
-                if st is None:          # mid-prefill: nothing to harvest
-                    continue
-                delta = int(self._n_gen[slot]) - st["prev_n_gen"]
-                st["out"].extend(emitted[slot, :delta].tolist())
-                st["prev_n_gen"] = int(self._n_gen[slot])
-                if self._done[slot]:
-                    self._finish_slot(sched, state, slot, Status.OK, now,
-                                      results)
-            if self.kv_integrity and self._has_ssm:
-                self._ssm_rearm()
+                for slot in list(sched.active):
+                    st = state.get(slot)
+                    if st is None:      # mid-prefill: nothing to harvest
+                        continue
+                    delta = int(self._n_gen[slot]) - st["prev_n_gen"]
+                    st["out"].extend(emitted[slot, :delta].tolist())
+                    st["prev_n_gen"] = int(self._n_gen[slot])
+                    if self._done[slot]:
+                        self._finish_slot(sched, state, slot, Status.OK,
+                                          now, results)
+                if self.kv_integrity and self._has_ssm:
+                    self._ssm_rearm()
+            loop.end()
             if progress_cb is not None:
                 progress_cb(self, sched)
         self._fault_plan = None

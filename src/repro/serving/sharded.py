@@ -53,7 +53,7 @@ from repro.models.common import ModelConfig
 from repro.models.kvcache import kv_slot_checksum, ssm_state_checksum
 from repro.sharding import (mesh_fingerprint, shard_map_manual,
                             slot_cache_specs)
-from .engine import cached_program
+from .engine import cached_program, named_jit
 from .scheduler import (PREFILLING, ContinuousEngine,
                         ShardedSlotScheduler, SlotScheduler)
 from .snapshot import take_owner_row
@@ -176,7 +176,7 @@ class ShardedContinuousEngine(ContinuousEngine):
         # different n_slots on the SAME mesh map slots differently
         self._prefill = cached_program(
             ("admit", cfg, kv, max_len, mk, nloc),
-            lambda: jax.jit(shard_map_manual(
+            lambda: named_jit("admit", shard_map_manual(
                 admit_body, mesh,
                 in_specs=(_R, _R, cspec, _R, _R, _R),
                 out_specs=(_Pd, _Pd, cspec))))
@@ -187,7 +187,7 @@ class ShardedContinuousEngine(ContinuousEngine):
 
         self._reset = cached_program(
             ("reset", cfg, mk, nloc),
-            lambda: jax.jit(shard_map_manual(
+            lambda: named_jit("reset_slot", shard_map_manual(
                 reset_body, mesh, in_specs=(cspec, _R), out_specs=cspec)))
 
         # the decode chunk body IS the unsharded one — decode is row-
@@ -207,8 +207,10 @@ class ShardedContinuousEngine(ContinuousEngine):
                     body = functools.partial(
                         ContinuousEngine._chunk_fn, cfg=cfg, kv_fmt=kv,
                         n_steps=n_steps, greedy=greedy)
-                    fn = memo[(n_steps, greedy)] = jax.jit(shard_map_manual(
-                        body, mesh, in_specs=chunk_in, out_specs=chunk_out))
+                    fn = memo[(n_steps, greedy)] = named_jit(
+                        "decode_chunk", shard_map_manual(
+                            body, mesh, in_specs=chunk_in,
+                            out_specs=chunk_out))
                 return fn(params, tok, cache, keys, done, n_gen, max_new,
                           temp, stop, live, poison)
 
@@ -239,9 +241,10 @@ class ShardedContinuousEngine(ContinuousEngine):
                             ContinuousEngine._spec_chunk_fn, cfg=cfg,
                             kv_fmt=kv, k=k, n_rounds=n_rounds,
                             greedy=greedy)
-                        fn = memo[(k, n_rounds, greedy)] = jax.jit(
-                            shard_map_manual(body, mesh, in_specs=spec_in,
-                                             out_specs=spec_out))
+                        fn = memo[(k, n_rounds, greedy)] = named_jit(
+                            "spec_chunk", shard_map_manual(
+                                body, mesh, in_specs=spec_in,
+                                out_specs=spec_out))
                     return fn(params, draft, tok, cache, keys, done,
                               n_gen, max_new, temp, stop, live, poison,
                               spec_k)
@@ -260,7 +263,7 @@ class ShardedContinuousEngine(ContinuousEngine):
 
         self._snap = cached_program(
             ("snap", cfg, kv, mk, nloc),
-            lambda: jax.jit(shard_map_manual(
+            lambda: named_jit("snap", shard_map_manual(
                 snap_body, mesh, in_specs=(cspec, _R), out_specs=cspec)))
 
         def restore_body(cache, solo, slot):
@@ -272,7 +275,7 @@ class ShardedContinuousEngine(ContinuousEngine):
 
         self._restore_prog = cached_program(
             ("restore", cfg, kv, mk, nloc),
-            lambda: jax.jit(shard_map_manual(
+            lambda: named_jit("restore", shard_map_manual(
                 restore_body, mesh, in_specs=(cspec, _R, _R),
                 out_specs=cspec)))
 
@@ -287,7 +290,7 @@ class ShardedContinuousEngine(ContinuousEngine):
 
                 self._kv_check = cached_program(
                     ("kv_check", cfg, kv, mk),
-                    lambda: jax.jit(shard_map_manual(
+                    lambda: named_jit("kv_check", shard_map_manual(
                         kv_body, mesh, in_specs=(cspec, _Pd, _R),
                         out_specs=_Pd)))
             if self._has_ssm:
@@ -296,7 +299,7 @@ class ShardedContinuousEngine(ContinuousEngine):
 
                 self._ssm_check = cached_program(
                     ("ssm_check", cfg, mk),
-                    lambda: jax.jit(shard_map_manual(
+                    lambda: named_jit("ssm_check", shard_map_manual(
                         ssm_body, mesh, in_specs=(cspec,),
                         out_specs=_Pd)))
 
@@ -342,11 +345,12 @@ class ShardedContinuousEngine(ContinuousEngine):
                 if fn is None:
                     body = functools.partial(lane_body,
                                              with_head=with_head)
-                    fn = memo[with_head] = jax.jit(shard_map_manual(
-                        body, mesh,
-                        in_specs=(_R, _Pd, cspec, lspec, _Pd, _Pd, _Pd,
-                                  _Pd, _Pd),
-                        out_specs=(_Pd, cspec, lspec)))
+                    fn = memo[with_head] = named_jit(
+                        "lane_chunk", shard_map_manual(
+                            body, mesh,
+                            in_specs=(_R, _Pd, cspec, lspec, _Pd, _Pd, _Pd,
+                                      _Pd, _Pd),
+                            out_specs=(_Pd, cspec, lspec)))
                 return fn(params, toks, cache, lane, slot, offset,
                           n_valid, active, wrapped)
 
@@ -369,7 +373,7 @@ class ShardedContinuousEngine(ContinuousEngine):
 
         self._finish = cached_program(
             ("finish", cfg, mk, nloc),
-            lambda: jax.jit(shard_map_manual(
+            lambda: named_jit("lane_finish", shard_map_manual(
                 finish_body, mesh,
                 in_specs=(_R, _R, _R, cspec, _R, _R),
                 out_specs=(_Pd, _Pd, cspec))))
@@ -388,7 +392,7 @@ class ShardedContinuousEngine(ContinuousEngine):
         cfg, kv = self.cfg, self._kv
         fn = cached_program(
             ("cont_chunk", cfg, kv, None),
-            lambda: jax.jit(functools.partial(
+            lambda: named_jit("decode_chunk", functools.partial(
                 ContinuousEngine._chunk_fn, cfg=cfg, kv_fmt=kv),
                 static_argnames=("n_steps", "greedy")))
         b = self.slots_per_shard
@@ -529,7 +533,8 @@ class ShardedContinuousEngine(ContinuousEngine):
             self.params, batch, self.cache, jnp.int32(slot), key,
             jnp.float32(req.temperature))
         owner = slot // self.slots_per_shard
-        return np.asarray(tok0)[owner], np.asarray(keys)[owner]
+        with self._loop.span("serve.lane_wait"):
+            return np.asarray(tok0)[owner], np.asarray(keys)[owner]
 
     # per-shard lane cursors: {shard: cursor}; a missing key = idle lane
     def _park_lane(self) -> None:
@@ -590,29 +595,38 @@ class ShardedContinuousEngine(ContinuousEngine):
             wrap[shard] = off >= self._lane_rows
             if off + nv >= t:
                 finals[shard] = t
-        out, self.cache, self.lane = self._lane_fn(
-            self.params, toks, self.cache, self.lane, jnp.asarray(lslot),
-            jnp.asarray(offs), jnp.asarray(nval), jnp.asarray(act),
-            jnp.asarray(wrap), with_head=bool(finals))
-        for shard, pf in self._pf.items():
-            if act[shard]:
-                pf["offset"] += int(nval[shard])
-        for shard, t in finals.items():
-            pf = self._pf.pop(shard)
-            slot, req = pf["slot"], pf["req"]
-            # out row `shard` is the owner's final-chunk logits
-            tok0, keys, self.cache = self._finish(
-                out[shard:shard + 1], jax.random.PRNGKey(req.seed),
-                jnp.float32(req.temperature), self.cache,
-                jnp.int32(slot), jnp.int32(t))
-            self._arm_slot(slot, req, np.asarray(tok0)[shard],
-                           np.asarray(keys)[shard])
-            sched.mark_decoding(slot)
-            state[slot] = {"admit_time": pf["admit_time"], "out": [],
-                           "prev_n_gen": 0,
-                           "queue_delay": (pf["admit_time"]
-                                           - req.arrival_time),
-                           "ttft": clock() - req.arrival_time,
-                           "decode_spent": 0.0}
-            self._emit("prefill-done", uid=req.uid, shard=shard,
-                       slot=slot, prompt=t, ttft=state[slot]["ttft"])
+        busy = sorted(self._pf)
+        with self._loop.span(
+                "serve.lane", uid=[self._pf[s]["req"].uid for s in busy],
+                offset=[int(offs[s]) for s in busy],
+                n_valid=[int(nval[s]) for s in busy], final=sorted(finals)):
+            self._loop.add(lane_tokens=nval.sum())
+            out, self.cache, self.lane = self._lane_fn(
+                self.params, toks, self.cache, self.lane, jnp.asarray(lslot),
+                jnp.asarray(offs), jnp.asarray(nval), jnp.asarray(act),
+                jnp.asarray(wrap), with_head=bool(finals))
+            for shard, pf in self._pf.items():
+                if act[shard]:
+                    pf["offset"] += int(nval[shard])
+            for shard, t in finals.items():
+                pf = self._pf.pop(shard)
+                slot, req = pf["slot"], pf["req"]
+                # out row `shard` is the owner's final-chunk logits
+                key = jax.random.PRNGKey(req.seed)
+                temp = jnp.float32(req.temperature)
+                with self._loop.span("serve.lane_wait"):
+                    tok0, keys, self.cache = self._finish(
+                        out[shard:shard + 1], key, temp, self.cache,
+                        jnp.int32(slot), jnp.int32(t))
+                    tok0 = np.asarray(tok0)[shard]
+                    keys = np.asarray(keys)[shard]
+                self._arm_slot(slot, req, tok0, keys)
+                sched.mark_decoding(slot)
+                state[slot] = {"admit_time": pf["admit_time"], "out": [],
+                               "prev_n_gen": 0,
+                               "queue_delay": (pf["admit_time"]
+                                               - req.arrival_time),
+                               "ttft": clock() - req.arrival_time,
+                               "decode_spent": 0.0}
+                self._emit("prefill-done", uid=req.uid, shard=shard,
+                           slot=slot, prompt=t, ttft=state[slot]["ttft"])

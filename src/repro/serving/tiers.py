@@ -72,7 +72,7 @@ from repro.models import (init_cache, init_lane, prefill_chunk,
                           prefill_into_slot, read_cache_slot, reset_slot,
                           write_cache_slot)
 from repro.models.common import ModelConfig
-from .engine import cached_program
+from .engine import cached_program, named_jit
 from .scheduler import DECODING, ContinuousEngine, Request, SlotScheduler
 from .snapshot import (pack_device_state, slot_row_capacity,
                        unpack_device_state)
@@ -286,13 +286,15 @@ class TieredContinuousEngine(ContinuousEngine):
                 key = (("admit", cfg, kvf, max_len, mk) if af is None
                        else ("admit", cfg, kvf, max_len, mk, af))
                 self._prefills[(kvf, af)] = cached_program(
-                    key, lambda kvf=kvf, af=af: jax.jit(functools.partial(
-                        self._tier_admit_fn, cfg=cfg, kv_fmt=kvf,
-                        max_len=max_len, act_fmt=af)))
+                    key, lambda kvf=kvf, af=af: named_jit(
+                        "admit", functools.partial(
+                            self._tier_admit_fn, cfg=cfg, kv_fmt=kvf,
+                            max_len=max_len, act_fmt=af)))
             if kvf not in self._chunks:
                 self._chunks[kvf] = cached_program(
                     ("cont_chunk", cfg, kvf, mk),
-                    lambda kvf=kvf: jax.jit(
+                    lambda kvf=kvf: named_jit(
+                        "decode_chunk",
                         functools.partial(self._chunk_fn, cfg=cfg,
                                           kv_fmt=kvf),
                         static_argnames=("n_steps", "greedy")))
@@ -303,12 +305,14 @@ class TieredContinuousEngine(ContinuousEngine):
         # retraces per arena pytree), so one program each serves all tiers
         self._reset = cached_program(
             ("reset", cfg, mk),
-            lambda: jax.jit(functools.partial(reset_slot, cfg)))
+            lambda: named_jit("reset_slot",
+                              functools.partial(reset_slot, cfg)))
         self._snap = cached_program(
-            ("snap", cfg, self._kv, mk), lambda: jax.jit(read_cache_slot))
+            ("snap", cfg, self._kv, mk),
+            lambda: named_jit("snap", read_cache_slot))
         self._restore_prog = cached_program(
             ("restore", cfg, self._kv, mk),
-            lambda: jax.jit(write_cache_slot))
+            lambda: named_jit("restore", write_cache_slot))
 
     def _build_lane(self) -> None:
         cfg, mk = self.cfg, self._mesh_key
@@ -321,20 +325,23 @@ class TieredContinuousEngine(ContinuousEngine):
             if af is None:      # shares the base engine's lane program
                 self._lane_fns[(kvf, af)] = cached_program(
                     ("lane", cfg, kvf, self.p_chunk, mk),
-                    lambda kvf=kvf: jax.jit(functools.partial(
-                        self._lane_chunk_fn, cfg=cfg, kv_fmt=kvf),
+                    lambda kvf=kvf: named_jit(
+                        "lane_chunk", functools.partial(
+                            self._lane_chunk_fn, cfg=cfg, kv_fmt=kvf),
                         static_argnames=("with_head", "wrapped")))
             else:
                 self._lane_fns[(kvf, af)] = cached_program(
                     ("lane", cfg, kvf, self.p_chunk, mk, af),
-                    lambda kvf=kvf, af=af: jax.jit(functools.partial(
-                        self._tier_lane_fn, cfg=cfg, kv_fmt=kvf,
-                        act_fmt=af),
+                    lambda kvf=kvf, af=af: named_jit(
+                        "lane_chunk", functools.partial(
+                            self._tier_lane_fn, cfg=cfg, kv_fmt=kvf,
+                            act_fmt=af),
                         static_argnames=("with_head", "wrapped")))
         dspec = self.tiers[self.default_tier]
         self._lane_fn = self._lane_fns[(dspec.kv_fmt, dspec.act_fmt)]
         self._finish = cached_program(
-            ("finish", cfg, mk), lambda: jax.jit(self._finish_prefill_fn))
+            ("finish", cfg, mk),
+            lambda: named_jit("lane_finish", self._finish_prefill_fn))
 
     # -- jitted bodies ------------------------------------------------------
 
@@ -378,54 +385,29 @@ class TieredContinuousEngine(ContinuousEngine):
         self._slot_tier[slot] = self._tier_of(req)
         return super()._start_prefill(sched, slot, req, now, shard)
 
-    def _advance_lane(self, sched: SlotScheduler, state: Dict[int, Any],
-                      clock) -> None:
-        """Base ``_advance_lane`` with the in-flight prefill routed to its
-        tier's lane program, prefill weights and KV arena."""
-        now = clock()
-        while self._pf is None:
-            adm = sched.next_admission(now)
-            if adm is None:
-                return
-            slot, req = adm
-            snap = sched.resumable.pop(req.uid, None)
-            if snap is not None:
-                self._resume(sched, state, slot, req, snap, clock)
-                continue
-            self._pf = self._start_prefill(sched, slot, req, now)
-        pf = self._pf
-        slot, req, off = pf["slot"], pf["req"], pf["offset"]
+    def _lane_dispatch(self, req: Request, toks, slot: int, off: int,
+                       n_valid: int, final: bool):
+        """The base lane chunk routed to ``req``'s tier: its lane program,
+        prefill weights and KV arena."""
         name = self._tier_of(req)
         spec = self.tiers[name]
         kvf = spec.kv_fmt
-        t = len(req.tokens)
-        n_valid = min(self.p_chunk, t - off)
-        final = off + n_valid >= t
-        chunk_toks = np.zeros((1, self.p_chunk), np.int32)
-        chunk_toks[0, :n_valid] = req.tokens[off:off + n_valid]
         logits, self._caches[kvf], self.lane = \
             self._lane_fns[(kvf, spec.act_fmt)](
-                self._prefill_params[name], chunk_toks, self._caches[kvf],
+                self._prefill_params[name], toks, self._caches[kvf],
                 self.lane, jnp.int32(slot), jnp.int32(off),
                 jnp.int32(n_valid), with_head=final,
                 wrapped=off >= self._lane_rows)
-        pf["offset"] = off + n_valid
-        if not final:
-            return
-        tok0, key, self._caches[kvf] = self._finish(
-            logits, jax.random.PRNGKey(req.seed),
-            jnp.float32(req.temperature), self._caches[kvf],
-            jnp.int32(slot), t)
-        self._arm_slot(slot, req, tok0, key)
-        sched.mark_decoding(slot)
-        state[slot] = {"admit_time": pf["admit_time"], "out": [],
-                       "prev_n_gen": 0,
-                       "queue_delay": pf["admit_time"] - req.arrival_time,
-                       "ttft": clock() - req.arrival_time,
-                       "decode_spent": 0.0}
-        self._emit("prefill-done", uid=req.uid, slot=slot, prompt=t,
-                   ttft=state[slot]["ttft"])
-        self._pf = None
+        return logits
+
+    def _finish_dispatch(self, logits, req: Request, slot: int):
+        kvf = self.tiers[self._tier_of(req)].kv_fmt
+        key = jax.random.PRNGKey(req.seed)
+        temp, at = jnp.float32(req.temperature), jnp.int32(slot)
+        with self._loop.span("serve.lane_wait"):
+            tok0, key, self._caches[kvf] = self._finish(
+                logits, key, temp, self._caches[kvf], at, len(req.tokens))
+        return tok0, key
 
     def _reset_dispatch(self, slot: int) -> None:
         kvf = self.tiers[self._slot_tier[slot]].kv_fmt
@@ -459,29 +441,36 @@ class TieredContinuousEngine(ContinuousEngine):
             spec = self.tiers[self._slot_tier[int(s)]]
             groups.setdefault((spec.weight_fmt, spec.kv_fmt),
                               []).append(int(s))
+        loop = self._loop
         for wf, kvf in sorted(groups, key=repr):
             slots = groups[(wf, kvf)]
             mask = np.zeros((self.n_slots,), bool)
             mask[slots] = True
             greedy = bool((np.where(mask, self._temp, 0.0) == 0.0).all())
-            (emitted, tok, cache, keys, done, n_gen,
-             finite) = self._chunks[kvf](
-                self._wparams[wf], jnp.asarray(self._tok),
-                self._caches[kvf], jnp.asarray(self._keys),
-                jnp.asarray(self._done | ~mask),
-                jnp.asarray(self._n_gen), jnp.asarray(self._max_new),
-                jnp.asarray(self._temp), jnp.asarray(self._stop),
-                jnp.asarray(self._live & mask),
-                jnp.asarray(np.asarray(poison) & mask),
-                n_steps=self.chunk, greedy=greedy)
+            with loop.span("serve.upload"):
+                args = (jnp.asarray(self._tok), self._caches[kvf],
+                        jnp.asarray(self._keys),
+                        jnp.asarray(self._done | ~mask),
+                        jnp.asarray(self._n_gen), jnp.asarray(self._max_new),
+                        jnp.asarray(self._temp), jnp.asarray(self._stop),
+                        jnp.asarray(self._live & mask),
+                        jnp.asarray(np.asarray(poison) & mask))
+            with loop.span("serve.dispatch"):
+                (emitted, tok, cache, keys, done, n_gen,
+                 finite) = self._chunks[kvf](
+                    self._wparams[wf], *args, n_steps=self.chunk,
+                    greedy=greedy)
             self._caches[kvf] = cache
-            got = jax.device_get((emitted, tok, keys, done, n_gen, finite))
-            self._tok[mask] = np.asarray(got[1])[mask]
-            self._keys[mask] = np.asarray(got[2], np.uint32)[mask]
-            self._done[mask] = np.asarray(got[3])[mask]
-            self._n_gen[mask] = np.asarray(got[4])[mask]
-            emitted_all[mask] = np.asarray(got[0])[mask]
-            finite_all[mask] = np.asarray(got[5])[mask]
+            with loop.span("serve.wait"):
+                got = jax.device_get((emitted, tok, keys, done, n_gen,
+                                      finite))
+            with loop.span("serve.harvest"):
+                self._tok[mask] = np.asarray(got[1])[mask]
+                self._keys[mask] = np.asarray(got[2], np.uint32)[mask]
+                self._done[mask] = np.asarray(got[3])[mask]
+                self._n_gen[mask] = np.asarray(got[4])[mask]
+                emitted_all[mask] = np.asarray(got[0])[mask]
+                finite_all[mask] = np.asarray(got[5])[mask]
         return emitted_all, finite_all
 
     # -- degraded-KV shedding rung ------------------------------------------
