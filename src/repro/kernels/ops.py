@@ -22,12 +22,13 @@ from repro.core.quantize import (quantize_blocks, quantize_blocks_arith,
                                  to_blocks)
 from . import ref as kref
 from .decode_lib import code_group
-from .nxfp_attention import nxfp_decode_attention_pallas
+from .nxfp_attention import cache_planes, nxfp_decode_attention_pallas
 from .nxfp_matmul import nxfp_matmul_pallas
 from .nxfp_qq_matmul import nxfp_qq_matmul_pallas, qq_planes
 from .nxfp_quantize import nxfp_quantize_pack_pallas
 
-__all__ = ["qmatmul", "quantize_qtensor", "decode_attention"]
+__all__ = ["qmatmul", "quantize_qtensor", "decode_attention",
+           "decode_attention_planes"]
 
 # Encoder selector for quantize_qtensor (§Perf / DESIGN.md §2.5): "arith"
 # (default) = the fused pipeline — Pallas fused encode+pack where eligible,
@@ -231,28 +232,53 @@ def decode_attention(q, kq: QTensor, vq: QTensor, lengths, n_kv_heads: int,
     """Single-token attention over a quantized KV cache.
 
     q: (B, H, D) — unscaled query for the new token.
-    kq/vq: QTensor of the (B, S, KVH, D) cache, quantized along axis -1.
+    kq/vq: QTensor of the (B, S, KVH, D) cache, quantized along axis -1
+      (its row layout, taken to the plane view here).
     lengths: (B,) int32 valid context lengths.
     Returns (B, H, D) f32.
     """
+    bg = code_group(kq.fmt.bits)[1]
+    kp, km = cache_planes(kq.packed, kq.meta, bg)
+    vp, vm = cache_planes(vq.packed, vq.meta, bg)
+    assert km.shape[1] == n_kv_heads, (km.shape, n_kv_heads)
+    return decode_attention_planes(q, kp, km, vp, vm, lengths, kq.fmt,
+                                   impl=impl)
+
+
+def decode_attention_planes(q, k_packed, k_meta, v_packed, v_meta, lengths,
+                            fmt: BlockFormat, layer=None,
+                            impl: Optional[str] = None):
+    """``decode_attention`` over the serving cache's stored plane view.
+
+    k_packed/v_packed (L, B, KVH, Bg, D/P, S) uint8 and k_meta/v_meta
+    (L, B, KVH, NB, S) uint16 are the whole stack, of which layer
+    ``layer`` (traced int32) is attended, read in place by the kernel's
+    index map; with ``layer=None`` they are one layer's (no L axis).
+    Returns (B, H, D) f32.
+    """
+    if layer is None:
+        k_packed, k_meta, v_packed, v_meta = (
+            x[None] for x in (k_packed, k_meta, v_packed, v_meta))
+        layer = 0
     impl = _resolve(impl)
     b, h, d = q.shape
-    g = h // n_kv_heads
-    qg = (q.reshape(b, n_kv_heads, g, d).astype(jnp.float32) *
+    kvh = k_meta.shape[2]
+    g = h // kvh
+    qg = (q.reshape(b, kvh, g, d).astype(jnp.float32) *
           np.float32(1.0 / np.sqrt(d)))
     lengths2 = lengths.reshape(b, 1).astype(jnp.int32)
-    fmt = kq.fmt
+    layer = jnp.asarray(layer, jnp.int32)
     # quantization pads head_dim to a block multiple; pad q to match (the
     # padded K dims dequantize to 0 so scores are unchanged) & slice out.
-    d_pad = kq.packed.shape[-2] * fmt.block_size
+    d_pad = k_meta.shape[-2] * fmt.block_size
     if d_pad != d:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, 0), (0, d_pad - d)))
-    ts = _tile(kq.packed.shape[1], (512, 256, 128))
+    ts = _tile(k_meta.shape[-1], (512, 256, 128))
+    leaves = (k_packed, k_meta, v_packed, v_meta)
     if impl == "pallas" and _kernel_ok(fmt) and d_pad * ts <= _MAX_TILE_ELEMS:
         out = nxfp_decode_attention_pallas(
-            qg, kq.packed, kq.meta, vq.packed, vq.meta, lengths2, fmt,
-            tile_s=ts, interpret=_interpret())
+            qg, *leaves, lengths2, layer, fmt, tile_s=ts,
+            interpret=_interpret())
     else:
-        out = kref.decode_attention_ref(
-            qg, kq.packed, kq.meta, vq.packed, vq.meta, lengths2, fmt)
+        out = kref.decode_attention_ref(qg, *leaves, lengths2, layer, fmt)
     return out[..., :d].reshape(b, h, d)

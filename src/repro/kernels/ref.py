@@ -64,13 +64,16 @@ def dequant_cache_ref(packed, meta, fmt: BlockFormat):
 
 
 def decode_attention_ref(q, k_packed, k_meta, v_packed, v_meta, lengths,
-                         fmt: BlockFormat):
+                         layer, fmt: BlockFormat):
     """Oracle for nxfp_decode_attention_pallas. q: (B, KVH, G, D) (pre-scaled).
 
-    Full dequantization, exact softmax, per-sequence length masking.
+    Layer ``layer`` of the stacked plane-view leaves (L, B, KVH, Bg, D/P,
+    S) + (L, B, KVH, NB, S); full dequantization, exact softmax,
+    per-sequence length masking.
     """
-    k = dequant_cache_ref(k_packed, k_meta, fmt)            # (B, S, KVH, D)
-    v = dequant_cache_ref(v_packed, v_meta, fmt)
+    from .nxfp_attention import cache_rows
+    k = dequant_cache_ref(*cache_rows(k_packed[layer], k_meta[layer]), fmt)
+    v = dequant_cache_ref(*cache_rows(v_packed[layer], v_meta[layer]), fmt)
     scores = jnp.einsum("bhgd,bshd->bhgs", q.astype(jnp.float32), k,
                         preferred_element_type=jnp.float32)
     s = k.shape[1]

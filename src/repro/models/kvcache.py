@@ -10,7 +10,19 @@ rides the fused encode+pack quantize pipeline: on TPU one Pallas kernel
 writes packed uint8 + uint16 meta straight into the cache layout below —
 no int32 code intermediate, no separate repack pass (DESIGN.md §2).
 
-Cache pytrees hold a leading stacked-layer axis consumed by lax.scan.
+Cache pytrees hold a leading stacked-layer axis.  The decode and lane
+layer loops carry the dense attention leaves whole and write each
+layer's rows in place (the ``layer`` entry of a layer cache, DESIGN.md
+§7); SSM state and the paged cache ride lax.scan per layer.
+
+Packed leaves are STORED in the attention kernel's plane view, context
+minor (DESIGN.md §2.4): ``k_packed`` (L, B, KVH, Bg, D/P, S) uint8 and
+``k_meta`` (L, B, KVH, NB, S) uint16, so the kernel reads them as they
+lie and a token's write is one column per slot.  Slot surgery and
+snapshots speak the ROW layout — ``(L, 1, S, KVH, NB, bpb)`` /
+``(L, 1, S, KVH, NB)``, what ``quantize_qtensor`` emits — through
+``slot_rows`` / ``slot_store``; dense bf16 leaves are (L, B, S, KVH, hd)
+in both.
 
 Positions are PER SLOT: ``pos`` is a (B,) int32 vector, one ring pointer
 per batch slot, so slots advance independently — the invariant continuous
@@ -28,14 +40,18 @@ import jax.numpy as jnp
 
 from repro.core.formats import get_format
 from repro.core.pack import byte_fold, bytes_per_block
-from repro.core.qtensor import QTensor
-from repro.kernels.ops import decode_attention, quantize_qtensor
+from repro.kernels.decode_lib import code_group
+from repro.kernels.nxfp_attention import cache_planes, cache_rows
+from repro.kernels.ops import decode_attention_planes, quantize_qtensor
 from .common import ModelConfig
 
 # the attention-cache leaves covered by the per-slot integrity canary
 # (dense bf16 or NxFP packed+meta; SSM state is excluded — it integrates
 # every step, so it has no immutable prefix to checksum)
 _KV_LEAVES = ("k", "v", "k_packed", "k_meta", "v_packed", "v_meta")
+# the packed (codes, meta) pairs, stored in the plane view
+_PLANE_PAIRS = (("k_packed", "k_meta"), ("v_packed", "v_meta"))
+_PLANE_LEAVES = frozenset(n for pair in _PLANE_PAIRS for n in pair)
 
 # Paged-cache leaf naming (DESIGN.md §14): each dense leaf <name> has a
 # physical-page pool twin "pool_<name>" of shape (L, NP, page, ...tail),
@@ -54,15 +70,66 @@ def attn_cache_init(cfg: ModelConfig, n_layers: int, batch: int,
     kvh, hd = cfg.n_kv_heads, cfg.hd
     # windowed caches are always window-sized rings (slot = pos % window)
     s = cfg.sliding_window if cfg.sliding_window else max_len
+    # every leaf its own buffer: the serving programs donate the cache,
+    # and one buffer cannot be donated twice
     if kv_fmt is None:
-        z = jnp.zeros((n_layers, batch, s, kvh, hd), cfg.dtype)
-        return {"k": z, "v": z}
+        shape = (n_layers, batch, s, kvh, hd)
+        return {n: jnp.zeros(shape, cfg.dtype) for n in ("k", "v")}
     fmt = get_format(kv_fmt)
     nb = -(-hd // fmt.block_size)
     bpb = bytes_per_block(fmt.block_size, fmt.bits)
-    zc = jnp.zeros((n_layers, batch, s, kvh, nb, bpb), jnp.uint8)
-    zm = jnp.zeros((n_layers, batch, s, kvh, nb), jnp.uint16)
-    return {"k_packed": zc, "k_meta": zm, "v_packed": zc, "v_meta": zm}
+    bg = _group_bytes(kv_fmt)
+    codes = (n_layers, batch, kvh, bg, nb * bpb // bg, s)
+    meta = (n_layers, batch, kvh, nb, s)
+    return {"k_packed": jnp.zeros(codes, jnp.uint8),
+            "k_meta": jnp.zeros(meta, jnp.uint16),
+            "v_packed": jnp.zeros(codes, jnp.uint8),
+            "v_meta": jnp.zeros(meta, jnp.uint16)}
+
+
+def _group_bytes(kv_fmt: str) -> int:
+    """Bytes per code group (Bg) of the plane view for ``kv_fmt``."""
+    return code_group(get_format(kv_fmt).bits)[1]
+
+
+def slot_rows(layers):
+    """A cache group's packed pairs from the stored plane view to the row
+    layout (``cache_rows``); other leaves pass through.  The interchange
+    layout of ``lm.read_cache_slot`` and of slot snapshots."""
+    out = dict(layers)
+    for pk, mk in _PLANE_PAIRS:
+        if pk in layers:
+            out[pk], out[mk] = cache_rows(layers[pk], layers[mk])
+    return out
+
+
+def slot_store(rows, like):
+    """Inverse of ``slot_rows``: row-layout packed pairs into the plane
+    view of the stored group ``like`` (whose leaves give Bg)."""
+    if "k_packed" not in like:
+        return rows
+    return _to_planes(rows, like["k_packed"].shape[-3])
+
+
+def _to_planes(rows, bg: int):
+    """Row-layout packed pairs of a group -> the plane view (groups of
+    ``bg`` bytes); other leaves pass through."""
+    out = dict(rows)
+    for pk, mk in _PLANE_PAIRS:
+        if pk in rows:
+            out[pk], out[mk] = cache_planes(rows[pk], rows[mk], bg)
+    return out
+
+
+def row_capacity(layers) -> Optional[int]:
+    """Rows per slot (window or max_len) of a dense stored cache group's
+    attention leaves, None without any."""
+    for name in _KV_LEAVES:
+        if name in layers:
+            leaf = layers[name]
+            return int(leaf.shape[-1] if name in _PLANE_LEAVES
+                       else leaf.shape[2])
+    return None
 
 
 def paged_attn_cache_init(cfg: ModelConfig, n_layers: int, batch: int,
@@ -84,16 +151,19 @@ def paged_attn_cache_init(cfg: ModelConfig, n_layers: int, batch: int,
             f"page_size {page_size} must divide the slot row capacity {s} "
             f"(sliding window or max_len)")
     block = jnp.zeros((n_layers, batch, s // page_size), jnp.int32)
-    if kv_fmt is None:
-        z = jnp.zeros((n_layers, n_pages, page_size, kvh, hd), cfg.dtype)
-        return {"block": block, "pool_k": z, "pool_v": z}
+    rows = (n_layers, n_pages, page_size, kvh)
+    if kv_fmt is None:                  # one buffer per leaf (donated)
+        return {"block": block,
+                **{n: jnp.zeros(rows + (hd,), cfg.dtype)
+                   for n in ("pool_k", "pool_v")}}
     fmt = get_format(kv_fmt)
     nb = -(-hd // fmt.block_size)
     bpb = bytes_per_block(fmt.block_size, fmt.bits)
-    zc = jnp.zeros((n_layers, n_pages, page_size, kvh, nb, bpb), jnp.uint8)
-    zm = jnp.zeros((n_layers, n_pages, page_size, kvh, nb), jnp.uint16)
-    return {"block": block, "pool_k_packed": zc, "pool_k_meta": zm,
-            "pool_v_packed": zc, "pool_v_meta": zm}
+    return {"block": block,
+            "pool_k_packed": jnp.zeros(rows + (nb, bpb), jnp.uint8),
+            "pool_k_meta": jnp.zeros(rows + (nb,), jnp.uint16),
+            "pool_v_packed": jnp.zeros(rows + (nb, bpb), jnp.uint8),
+            "pool_v_meta": jnp.zeros(rows + (nb,), jnp.uint16)}
 
 
 def paged_layer_view(layer_cache):
@@ -144,6 +214,12 @@ def _quantize_kv(x, kv_fmt: str):
     return qt.packed, qt.meta
 
 
+def _quantize_planes(x, kv_fmt: str):
+    """(B, T, KVH, hd) -> plane-view (codes (B, KVH, Bg, D/P, T), meta
+    (B, KVH, NB, T)): the stored layout's columns for T rows."""
+    return cache_planes(*_quantize_kv(x, kv_fmt), _group_bytes(kv_fmt))
+
+
 def _ring_place(x, window: int, t: int):
     """Store the last `window` of x (B, T, ...) at ring slots (pos % window)."""
     if t <= window:
@@ -168,10 +244,10 @@ def write_prefill(cfg: ModelConfig, k, v, kv_fmt: Optional[str],
 
     if kv_fmt is None:
         return {"k": place(k.astype(cfg.dtype)), "v": place(v.astype(cfg.dtype))}
-    kp, km = _quantize_kv(k, kv_fmt)
-    vp, vm = _quantize_kv(v, kv_fmt)
-    return {"k_packed": place(kp), "k_meta": place(km),
-            "v_packed": place(vp), "v_meta": place(vm)}
+    bg = _group_bytes(kv_fmt)
+    kp, km = cache_planes(*map(place, _quantize_kv(k, kv_fmt)), bg)
+    vp, vm = cache_planes(*map(place, _quantize_kv(v, kv_fmt)), bg)
+    return {"k_packed": kp, "k_meta": km, "v_packed": vp, "v_meta": vm}
 
 
 def write_prefill_at(cfg: ModelConfig, layer_cache, k, v, slot, offset,
@@ -184,17 +260,18 @@ def write_prefill_at(cfg: ModelConfig, layer_cache, k, v, slot, offset,
     ``kv_fmt`` is set (blocks run along head_dim, entirely inside one
     row, so the packed bytes are bit-identical to a whole-prompt cast).
     Rows >= ``n_valid`` (the padded tail of a fixed-shape partial chunk)
-    are routed out of range and DROPPED by the scatter, so a ragged final
-    chunk never touches rows it doesn't own.  Requires P <= window for
-    ring caches (distinct in-chunk rows; the engine asserts it).
+    are not written (the packed columns' window writes back what it
+    read; dense bf16 and paged rows are routed out of range and dropped
+    by a scatter), so a ragged final chunk never touches rows it doesn't
+    own.  Requires P <= window for ring caches (distinct in-chunk rows;
+    the engine asserts it).  With a ``layer`` entry the rows land in
+    that layer of the stacked cache, in place (DESIGN.md §7).
 
-    ``n_valid = 0`` routes EVERY row out of range — the whole call
-    becomes a cache no-op, which is how an idle shard rides the sharded
-    engine's fused lane dispatch (DESIGN.md §10).  Under the slot-sharded
-    manual shard_map the slot axis of ``layer_cache`` is a local shard
-    slice, so this scatter stays a single-device op per shard — but its
-    Mosaic lowering on the uint8 packed rows is a first-real-TPU-run
-    validation item (DESIGN.md §10, ROADMAP).
+    ``n_valid = 0`` writes no row — the whole call becomes a cache
+    no-op, which is how an idle shard rides the sharded engine's fused
+    lane dispatch (DESIGN.md §10).  Under the slot-sharded manual
+    shard_map the slot axis of ``layer_cache`` is a local shard slice,
+    so the write stays a single-device op per shard.
     """
     w = cfg.sliding_window
     pch = k.shape[1]
@@ -230,21 +307,70 @@ def write_prefill_at(cfg: ModelConfig, layer_cache, k, v, slot, offset,
                 "pool_v_packed": put(layer_cache["pool_v_packed"], vp),
                 "pool_v_meta": put(layer_cache["pool_v_meta"], vm)}
 
-    s = next(iter(layer_cache.values())).shape[1]
-    row = jnp.where(jnp.arange(pch) < n_valid, row, s)   # OOB -> dropped
-
-    def put(buf, val):
-        return buf.at[slot, row].set(val[0].astype(buf.dtype), mode="drop")
-
+    at = _layer_at(layer_cache)
     if kv_fmt is None:
-        return {"k": put(layer_cache["k"], k),
-                "v": put(layer_cache["v"], v)}
-    kp, km = _quantize_kv(k, kv_fmt)
-    vp, vm = _quantize_kv(v, kv_fmt)
-    return {"k_packed": put(layer_cache["k_packed"], kp),
-            "k_meta": put(layer_cache["k_meta"], km),
-            "v_packed": put(layer_cache["v_packed"], vp),
-            "v_meta": put(layer_cache["v_meta"], vm)}
+        s = layer_cache["k"].shape[len(at) + 1]
+        row = jnp.where(jnp.arange(pch) < n_valid, row, s)  # OOB: dropped
+
+        def put(buf, val):
+            return buf.at[at + (slot, row)].set(val[0].astype(buf.dtype),
+                                                mode="drop")
+
+        return dict(layer_cache, k=put(layer_cache["k"], k),
+                    v=put(layer_cache["v"], v))
+
+    def put_cols(buf, val):
+        return _put_columns(buf, val[0], at + (slot,), offset, n_valid,
+                            ring=bool(w))
+
+    kp, km = _quantize_planes(k, kv_fmt)
+    vp, vm = _quantize_planes(v, kv_fmt)
+    return dict(layer_cache,
+                k_packed=put_cols(layer_cache["k_packed"], kp),
+                k_meta=put_cols(layer_cache["k_meta"], km),
+                v_packed=put_cols(layer_cache["v_packed"], vp),
+                v_meta=put_cols(layer_cache["v_meta"], vm))
+
+
+def _put_columns(buf, val, lead, offset, n_valid, ring: bool):
+    """Write chunk columns ``val[..., i]``, i < ``n_valid``, at context
+    rows ``offset + i`` (mod S for a ring) of the plane-view leaf ``buf``
+    at index ``lead`` (layer and slot, or slot).
+
+    Each write is a read-modify-write of one P-wide column window (two
+    for a ring, whose rows may wrap), a ``dynamic_update_slice`` that
+    XLA performs in place; the window's columns outside the chunk's
+    valid rows write back what they held, so padded rows and an idle
+    call (``n_valid = 0``) change nothing.
+    """
+    s, p = buf.shape[-1], val.shape[-1]
+    wd = min(p, s)
+    # ext[i] is chunk row i (zero past the chunk), doubled so that any
+    # window start reads the rows of a ring rotation as one slice
+    ext = (val[..., :s] if p >= s else jnp.concatenate(
+        [val, jnp.zeros(val.shape[:-1] + (s - p,), val.dtype)], axis=-1))
+    ext = jnp.concatenate([ext, ext], axis=-1).astype(buf.dtype)
+    start = jnp.asarray(offset, jnp.int32) % s
+    zeros = (0,) * (buf.ndim - len(lead) - 1)
+    shape = (1,) * len(lead) + val.shape[:-1] + (wd,)
+    j = jnp.arange(wd, dtype=jnp.int32)
+    starts = [jnp.minimum(start, s - wd)] + ([jnp.int32(0)] if ring else [])
+    for a in starts:
+        c = (a - start) % s                     # window column 0's chunk row
+        new = jax.lax.dynamic_slice_in_dim(ext, c, wd, axis=-1)
+        idx = tuple(lead) + zeros + (a,)
+        old = jax.lax.dynamic_slice(buf, idx, shape)
+        keep = (c + j) % s < n_valid
+        buf = jax.lax.dynamic_update_slice(
+            buf, jnp.where(keep, new.reshape(shape), old), idx)
+    return buf
+
+
+def _layer_at(layer_cache):
+    """Leading index of the rows a layer owns: ``(layer,)`` for one layer
+    of a stacked cache (the ``layer`` entry, DESIGN.md §7), else ()."""
+    layer = layer_cache.get("layer")
+    return () if layer is None else (layer,)
 
 
 def _per_slot(pos, b: int):
@@ -259,7 +385,9 @@ def write_token(cfg: ModelConfig, layer_cache, k1, v1, pos,
 
     ``pos`` is (B,) int32 (a scalar broadcasts): each batch slot writes at
     its OWN ring slot (``pos[b] % window``), so ragged slots never touch a
-    neighbor's rows — a vmapped ``dynamic_update_slice`` per sequence.
+    neighbor's rows — one ``dynamic_update_slice`` per sequence.  With a
+    ``layer`` entry (one layer of the stacked cache, DESIGN.md §7) the
+    rows land at ``(layer, b, ...)`` of the whole stack, in place.
 
     ``live`` (B,) bool, when given, SUPPRESSES slot b's write for
     ``live[b] == False`` (the row keeps its old value).  The continuous
@@ -304,33 +432,41 @@ def write_token(cfg: ModelConfig, layer_cache, k1, v1, pos,
                 "pool_v_packed": updp(layer_cache["pool_v_packed"], vp),
                 "pool_v_meta": updp(layer_cache["pool_v_meta"], vm)}
 
-    def upd(buf, val):
-        if live is None:
-            def one(row, v, s):
-                idx = (s,) + (0,) * (row.ndim - 1)
-                return jax.lax.dynamic_update_slice(
-                    row, v.astype(row.dtype), idx)
-            return jax.vmap(one)(buf, val, slot)
+    at = _layer_at(layer_cache)
 
-        # gate at the ROW level: a not-live slot writes its old row back,
-        # so the update stays a single in-place-able dynamic_update_slice
-        # per slot — no full-cache select on the decode hot path
-        def one(row, v, s, lv):
-            idx = (s,) + (0,) * (row.ndim - 1)
-            cur = jax.lax.dynamic_slice(row, idx, v.shape)
-            return jax.lax.dynamic_update_slice(
-                row, jnp.where(lv, v.astype(row.dtype), cur), idx)
-        return jax.vmap(one)(buf, val, slot, live)
+    def upd(buf, val, plane: bool):
+        # one dynamic_update_slice per slot, unrolled: XLA performs each
+        # in place in the layout the attention kernel reads, where a
+        # scatter (what a vmapped update becomes) or a loop of updates
+        # makes it relayout the whole buffer.  A not-live slot writes
+        # its old row back (row-level gating: no full-buffer select).
+        for b in range(val.shape[0]):
+            v = val[b:b + 1].astype(buf.dtype)[(None,) * len(at)]
+            idx = at + (b,) + _row_index(v.ndim - len(at) - 1, slot[b],
+                                         plane)
+            if live is not None:
+                v = jnp.where(live[b], v,
+                              jax.lax.dynamic_slice(buf, idx, v.shape))
+            buf = jax.lax.dynamic_update_slice(buf, v, idx)
+        return buf
 
     if kv_fmt is None:
-        return {"k": upd(layer_cache["k"], k1),
-                "v": upd(layer_cache["v"], v1)}
-    kp, km = _quantize_kv(k1, kv_fmt)
-    vp, vm = _quantize_kv(v1, kv_fmt)
-    return {"k_packed": upd(layer_cache["k_packed"], kp),
-            "k_meta": upd(layer_cache["k_meta"], km),
-            "v_packed": upd(layer_cache["v_packed"], vp),
-            "v_meta": upd(layer_cache["v_meta"], vm)}
+        return dict(layer_cache, k=upd(layer_cache["k"], k1, False),
+                    v=upd(layer_cache["v"], v1, False))
+    kp, km = _quantize_planes(k1, kv_fmt)
+    vp, vm = _quantize_planes(v1, kv_fmt)
+    return dict(layer_cache,
+                k_packed=upd(layer_cache["k_packed"], kp, True),
+                k_meta=upd(layer_cache["k_meta"], km, True),
+                v_packed=upd(layer_cache["v_packed"], vp, True),
+                v_meta=upd(layer_cache["v_meta"], vm, True))
+
+
+def _row_index(ndim: int, row, plane: bool):
+    """Start index, after the slot, of one context row of a slot's
+    ``ndim``-dim leaf: the last axis of a plane view, else the first."""
+    zeros = (0,) * (ndim - 1)
+    return zeros + (row,) if plane else (row,) + zeros
 
 
 def kv_slot_checksum(cfg: ModelConfig, cache, upto, horizon=None):
@@ -375,6 +511,8 @@ def kv_slot_checksum(cfg: ModelConfig, cache, upto, horizon=None):
         leaf = layers.get(name)
         if leaf is None:
             continue
+        if name in _PLANE_LEAVES:                       # rows minor
+            leaf = jnp.moveaxis(leaf, -1, 2)
         f = byte_fold(leaf, 3)                          # (L, B, S)
         s = leaf.shape[2]
         rw = 2 * jnp.arange(s, dtype=jnp.uint32) + 1
@@ -442,19 +580,21 @@ def attend_decode(cfg: ModelConfig, layer_cache, q, pos,
         # attention code — shapes, masking and reduction order are
         # identical to the fixed-slot engine, so outputs are bitwise
         # equal on valid rows (garbage rows are masked by `lengths`).
+        # The view is in the row layout; the kernel reads the plane view.
         layer_cache = paged_layer_view(layer_cache)
+        if kv_fmt is not None:
+            layer_cache = _to_planes(layer_cache, _group_bytes(kv_fmt))
 
+    layer = layer_cache.get("layer")
     if kv_fmt is not None:
-        fmt = get_format(kv_fmt)
-        s = layer_cache["k_packed"].shape[1]
-        shape = (b, s, kvh, hd)
-        kq = QTensor(layer_cache["k_packed"], layer_cache["k_meta"],
-                     fmt.name, shape, -1, hd)
-        vq = QTensor(layer_cache["v_packed"], layer_cache["v_meta"],
-                     fmt.name, shape, -1, hd)
-        return decode_attention(q, kq, vq, lengths, kvh)
+        return decode_attention_planes(
+            q, layer_cache["k_packed"], layer_cache["k_meta"],
+            layer_cache["v_packed"], layer_cache["v_meta"], lengths,
+            get_format(kv_fmt), layer=layer)
 
     k, v = layer_cache["k"], layer_cache["v"]                  # (B,S,KVH,hd)
+    if layer is not None:
+        k, v = k[layer], v[layer]
     g = h // kvh
     qg = q.reshape(b, kvh, g, hd).astype(jnp.float32) * (hd ** -0.5)
     scores = jnp.einsum("bhgd,bshd->bhgs", qg, k.astype(jnp.float32),

@@ -12,6 +12,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.core.qtensor import QTensor, QuantPolicy, direct_cast_tree
 from repro.kernels.ops import quantize_qtensor
@@ -21,7 +22,8 @@ from .blocks import (init_layer, layer_decode, layer_forward,
                      layer_prefill_chunk, layer_verify)
 from .common import (ModelConfig, dense, gated_update_slice, ninit, rmsnorm,
                      split_keys)
-from .kvcache import ssm_cache_init, write_prefill, write_token
+from .kvcache import (_KV_LEAVES, slot_rows, slot_store, ssm_cache_init,
+                      write_prefill, write_token)
 
 Params = Dict[str, Any]
 
@@ -419,16 +421,15 @@ def prefill_chunk(cfg: ModelConfig, params: Params, tokens, cache, slot,
                  + jnp.arange(pch, dtype=jnp.int32))
     first = jnp.asarray(offset == 0)
 
-    def body(h, xs):
-        lp, lane_l, cache_l = xs
-        h, new_lane_l, new_cache_l = layer_prefill_chunk(
+    def body(h, xs, cache_l):
+        lp, lane_l = xs
+        return layer_prefill_chunk(
             cfg, lp, h, lane_l, cache_l, slot, positions, offset, n_valid,
             kind, kv_fmt, first, active=active, wrapped=wrapped,
             act_fmt=act_fmt)
-        return h, (new_lane_l, new_cache_l)
 
-    x, (new_lane, new_layers) = jax.lax.scan(
-        body, x, (params["layers"], lane, cache["layers"]))
+    x, new_lane, new_layers = _layer_scan(
+        body, x, (params["layers"], lane), cache["layers"])
     # the slot's pos stays parked while PREFILLING (its decode-chunk
     # writes are live-masked); the engine sets pos[slot] at completion
     new_cache = dict(cache, layers=new_layers)
@@ -437,6 +438,56 @@ def prefill_chunk(cfg: ModelConfig, params: Params, tokens, cache, slot,
         return last[:, 0], new_cache, new_lane
     logits = _head(cfg, params, last)
     return logits[:, 0], new_cache, new_lane
+
+
+def _stacked_kv(layers):
+    """The dense attention leaves of a stacked layer cache, or None.
+
+    These ride the layer loop's carry whole and each layer writes its
+    rows into them in place (``kvcache.write_token`` /
+    ``write_prefill_at`` with the ``layer`` entry), so no layer's cache
+    slice is ever materialised (DESIGN.md §7).  A paged cache (``block``
+    leaf) keeps the scan over per-layer slices, and a cache without
+    attention leaves (pure SSM) has nothing to carry.
+    """
+    if "block" in layers:
+        return None
+    kv = {n: v for n, v in layers.items() if n in _KV_LEAVES}
+    return kv or None
+
+
+def _layer_scan(body, x, xs, layers, in_place: bool = True):
+    """Scan ``body(h, xs_l, layer_cache) -> (h, ys_l, new_layer_cache)``
+    over the stacked layers; returns (x, ys, new layers).
+
+    With ``in_place``, the dense attention leaves (``_stacked_kv``) ride
+    the carry whole, pinned to the row-major layout the attention kernel
+    reads (with no kernel in the loop, XLA lays the lane's stack out in
+    rows and relayouts it on entry and exit), and each layer's cache is
+    those leaves plus its ``layer`` index; the rest (SSM state) and
+    ``xs`` are scanned per layer.
+    """
+    kv = _stacked_kv(layers) if in_place else None
+    if kv is None:
+        def step(h, xs_c):
+            h, ys, nc = body(h, *xs_c)
+            return h, (ys, nc)
+
+        x, (ys, new) = jax.lax.scan(step, x, (xs, layers))
+        return x, ys, new
+
+    def step_kv(carry, xs_c):
+        h, kv, l = carry
+        xs_l, lc = xs_c
+        h, ys, nc = body(h, xs_l, dict(lc, layer=l, **kv))
+        kv = {n: with_layout_constraint(nc[n], Layout(tuple(range(
+            nc[n].ndim)))) for n in kv}
+        return (h, kv, l + 1), (ys, {n: nc[n] for n in lc})
+
+    rest = {n: v for n, v in layers.items() if n not in kv}
+    (x, kv, _), (ys, rest) = jax.lax.scan(
+        step_kv, (x, kv, jnp.int32(0)), (xs, rest))
+    return x, ys, dict(rest, **kv)
 
 
 def decode_step(cfg: ModelConfig, params: Params, tokens, cache,
@@ -460,15 +511,15 @@ def decode_step(cfg: ModelConfig, params: Params, tokens, cache,
     if fam in _KIND or fam == "audio":
         kind = _KIND.get(fam, "encdec")
 
-        def body(h, xs):
-            lp, lc = xs
+        def body(h, lp, lc):
             h, nc = layer_decode(cfg, lp, h, lc, pos, kind, kv_fmt,
                                  live=live)
-            return h, nc
+            return h, None, nc
 
-        x, layer_caches = jax.lax.scan(
-            body, x, (params["layers"], cache["layers"]))
-        new_cache["layers"] = layer_caches
+        # audio keeps the scan over per-layer slices
+        x, _, new_cache["layers"] = _layer_scan(
+            body, x, params["layers"], cache["layers"],
+            in_place=fam in _KIND)
     elif fam == "vlm":
         def group(h, xs):
             (lp_self, lc_self), (lp_cross, lc_cross) = xs
@@ -855,7 +906,8 @@ def write_cache_slot(cache: Dict[str, Any], solo: Dict[str, Any], slot,
     neighbor slots — K/V rows, ring meta, SSM state and the slot's ``pos``
     all land atomically (one fused jit).  ``apply`` (traced bool) makes
     the whole merge a value-gated no-op (sharded owner masking — see
-    ``common.gated_update_slice``).
+    ``common.gated_update_slice``).  ``solo`` is in the row layout
+    ``read_cache_slot`` returns (``kvcache.slot_rows``).
     """
     new: Dict[str, Any] = {"pos": gated_update_slice(
         cache["pos"], jnp.asarray(solo["pos"], jnp.int32), (slot,), apply)}
@@ -873,7 +925,7 @@ def write_cache_slot(cache: Dict[str, Any], solo: Dict[str, Any], slot,
             return gated_update_slice(leaf, s_leaf.astype(leaf.dtype),
                                       tuple(idx), apply)
 
-        new[name] = jax.tree.map(put, group, solo[name])
+        new[name] = jax.tree.map(put, group, slot_store(solo[name], group))
     return new
 
 
@@ -886,6 +938,8 @@ def read_cache_slot(cache: Dict[str, Any], slot):
     meta and SSM state are sliced raw, never dequantized.  Shapes are
     slot-independent (one compiled program serves every slot), which is
     what makes live snapshot/migrate/restore cheap on the serving path.
+    Packed leaves come back in the row layout (``kvcache.slot_rows``), so
+    snapshots of the fixed-slot and the paged cache are alike.
     """
     out: Dict[str, Any] = {"pos": jax.lax.dynamic_slice(
         cache["pos"], (jnp.asarray(slot, jnp.int32),), (1,))}
@@ -904,7 +958,7 @@ def read_cache_slot(cache: Dict[str, Any], slot):
             sizes[axis] = 1
             return jax.lax.dynamic_slice(leaf, idx, sizes)
 
-        out[name] = jax.tree.map(take, group)
+        out[name] = slot_rows(jax.tree.map(take, group))
     return out
 
 
@@ -926,6 +980,8 @@ def prefill_into_slot(cfg: ModelConfig, params: Params,
     assert batch["tokens"].shape[0] == 1, batch["tokens"].shape
     logits, solo = prefill(cfg, params, batch, max_len, kv_fmt,
                            act_fmt=act_fmt)
+    solo = {n: slot_rows(g) if isinstance(g, dict) else g
+            for n, g in solo.items()}
     return logits, write_cache_slot(cache, solo, slot, apply=apply)
 
 

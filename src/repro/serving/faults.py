@@ -147,9 +147,12 @@ def flip_kv_bytes(cache, slot: int, n_rows: int, rng, n_bytes: int = 1):
             view = arr.view(np.uint8).reshape(arr.shape + (2,))
         else:
             view = arr
-        row = int(rng.integers(min(n_rows, arr.shape[2])))
-        idx = tuple(int(rng.integers(d)) for d in view.shape)
-        idx = (idx[0], slot, row) + idx[3:]
+        # the stored packed leaves keep their context rows on the last
+        # axis (the plane view, models.kvcache)
+        row = int(rng.integers(min(n_rows, arr.shape[-1])))
+        idx = [int(rng.integers(d)) for d in view.shape]
+        idx[1], idx[arr.ndim - 1] = slot, row
+        idx = tuple(idx)
         view[idx] ^= np.uint8(rng.integers(1, 256))
         new_layers[name] = jax.device_put(arr, buf.sharding)
     return dict(cache, layers=new_layers)

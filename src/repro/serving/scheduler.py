@@ -911,10 +911,11 @@ class ContinuousEngine:
             ("admit", cfg, kv, max_len, mk),
             lambda: named_jit("admit", functools.partial(
                 self._admit_fn, cfg=cfg, kv_fmt=kv, max_len=max_len)))
-        self._reset = cached_program(
+        self._reset = self._keep_cache(cached_program(
             ("reset", cfg, mk),
             lambda: named_jit("reset_slot",
-                              functools.partial(reset_slot, cfg)))
+                              functools.partial(reset_slot, cfg),
+                              donate_argnums=0)), 0, None)
         self._chunk_jit = cached_program(
             ("cont_chunk", cfg, kv, mk),
             lambda: named_jit(
@@ -951,14 +952,37 @@ class ContinuousEngine:
     def _build_lane(self) -> None:
         cfg, kv, mk = self.cfg, self._kv, self._mesh_key
         self.lane = init_lane(cfg, self.max_len, self.p_chunk)
-        self._lane_fn = cached_program(
+        self._lane_fn = self._keep_cache(cached_program(
             ("lane", cfg, kv, self.p_chunk, mk),
-            lambda: named_jit("lane_chunk", functools.partial(
-                self._lane_chunk_fn, cfg=cfg, kv_fmt=kv),
-                static_argnames=("with_head", "wrapped")))
-        self._finish = cached_program(
+            lambda: self._lane_program(cfg, kv)), 2, 1)
+        self._finish = self._keep_cache(cached_program(
             ("finish", cfg, mk),
-            lambda: named_jit("lane_finish", self._finish_prefill_fn))
+            lambda: named_jit("lane_finish", self._finish_prefill_fn,
+                              donate_argnums=3)), 3, 2)
+
+    def _lane_program(self, cfg, kv):
+        """The unsharded lane program; its cache argument is donated."""
+        return named_jit("lane_chunk", functools.partial(
+            self._lane_chunk_fn, cfg=cfg, kv_fmt=kv),
+            static_argnames=("with_head", "wrapped"), donate_argnums=2)
+
+    def _keep_cache(self, prog, arg: int, out):
+        """``prog``, whose argument ``arg`` is the donated serving cache,
+        called so that the engine is always left on a live cache.
+
+        Donation lets XLA update the cache in place (DESIGN.md §7), and
+        deletes the buffers passed.  The engine's own dispatches rebind
+        ``self.cache`` to the returned cache (result ``out``, or the
+        whole result for None); so does this wrapper whenever the cache
+        passed is ``self.cache``, for callers that drop the result — a
+        probe or a timing loop run on the engine's own state.
+        """
+        def call(*args, **kwargs):
+            res = prog(*args, **kwargs)
+            if args[arg] is self.cache:
+                self.cache = res if out is None else res[out]
+            return res
+        return call
 
     # -- p_chunk autotuning (ROADMAP follow-up) -----------------------------
 
@@ -1013,6 +1037,7 @@ class ContinuousEngine:
                              f"{tuple(candidates)} satisfies the lane "
                              f"constraints of {cfg.name}")
         chunk_fn, params, cache, b = self._autotune_probes()
+        own = cache is self.cache
         zi = jnp.zeros((b,), jnp.int32)
         decode_s = self._time_best(lambda: chunk_fn(
             params, zi, cache, jnp.zeros((b, 2), jnp.uint32),
@@ -1030,13 +1055,21 @@ class ContinuousEngine:
             # transfers even though its fused program is keyed apart
             fn = cached_program(
                 ("lane", cfg, kv, p, None),
-                lambda: named_jit("lane_chunk", functools.partial(
-                    self._lane_chunk_fn, cfg=cfg, kv_fmt=kv),
-                    static_argnames=("with_head", "wrapped")))
+                lambda: self._lane_program(cfg, kv))
             toks = np.zeros((1, p), np.int32)
-            self.p_chunk_sweep[p] = self._time_best(lambda: fn(
-                params, toks, cache, lane, jnp.int32(0),
-                jnp.int32(0), jnp.int32(p), with_head=False))
+
+            def lane_call():
+                # the lane donates the cache: each call runs on the last
+                # call's result
+                nonlocal cache
+                out = fn(params, toks, cache, lane, jnp.int32(0),
+                         jnp.int32(0), jnp.int32(p), with_head=False)
+                cache = out[1]
+                return out
+
+            self.p_chunk_sweep[p] = self._time_best(lane_call)
+        if own:
+            self.cache = cache
         budget = stall_factor * decode_s
         ok = [p for p in cands if self.p_chunk_sweep[p] <= budget]
         best = (max(ok, key=lambda p: p / self.p_chunk_sweep[p]) if ok
@@ -1583,7 +1616,7 @@ class ContinuousEngine:
         req = sched.active[slot]
         solo = self._snap_dispatch(slot)
         pos = int(np.asarray(solo["pos"])[0])
-        rows = slot_row_capacity(solo)
+        rows = slot_row_capacity(self.cache)
         used = min(pos, rows) if rows is not None else 0
         st = state[slot]
         return SlotSnapshot(

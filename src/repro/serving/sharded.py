@@ -185,10 +185,11 @@ class ShardedContinuousEngine(ContinuousEngine):
             _, local, apply = _owner_apply(slot, nloc)
             return reset_slot(cfg, cache, local, apply=apply)
 
-        self._reset = cached_program(
+        self._reset = self._keep_cache(cached_program(
             ("reset", cfg, mk, nloc),
             lambda: named_jit("reset_slot", shard_map_manual(
-                reset_body, mesh, in_specs=(cspec, _R), out_specs=cspec)))
+                reset_body, mesh, in_specs=(cspec, _R), out_specs=cspec),
+                donate_argnums=0)), 0, None)
 
         # the decode chunk body IS the unsharded one — decode is row-
         # independent, so manual sharding is pure slicing (the bitwise
@@ -350,7 +351,8 @@ class ShardedContinuousEngine(ContinuousEngine):
                             body, mesh,
                             in_specs=(_R, _Pd, cspec, lspec, _Pd, _Pd, _Pd,
                                       _Pd, _Pd),
-                            out_specs=(_Pd, cspec, lspec)))
+                            out_specs=(_Pd, cspec, lspec)),
+                        donate_argnums=2)
                 return fn(params, toks, cache, lane, slot, offset,
                           n_valid, active, wrapped)
 
@@ -359,8 +361,9 @@ class ShardedContinuousEngine(ContinuousEngine):
         # ``ring`` rides the key: the cond-over-graphs trace differs from
         # the plain one, and ring-ness depends on max_len (via the lane
         # row count), which no other key component carries
-        self._lane_fn = cached_program(("lane", cfg, kv, pch, mk, ring),
-                                       build_lane_fn)
+        self._lane_fn = self._keep_cache(
+            cached_program(("lane", cfg, kv, pch, mk, ring), build_lane_fn),
+            2, 1)
         nloc = self.slots_per_shard
 
         def finish_body(logits, key, temperature, cache, slot, t):
@@ -371,12 +374,12 @@ class ShardedContinuousEngine(ContinuousEngine):
                 logits, key, temperature, cache, local, t, apply=apply)
             return tok0.reshape(1), key_out.reshape(1, 2), new_cache
 
-        self._finish = cached_program(
+        self._finish = self._keep_cache(cached_program(
             ("finish", cfg, mk, nloc),
             lambda: named_jit("lane_finish", shard_map_manual(
                 finish_body, mesh,
                 in_specs=(_R, _R, _R, cspec, _R, _R),
-                out_specs=(_Pd, _Pd, cspec))))
+                out_specs=(_Pd, _Pd, cspec)), donate_argnums=3)), 3, 2)
 
     def _autotune_probes(self):
         """Probe the PER-SHARD bodies on one device (see base docstring).

@@ -26,7 +26,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from ..models.kvcache import _KV_LEAVES
+from ..models.kvcache import _KV_LEAVES, row_capacity
 from ..models.lm import _batch_axis
 
 __all__ = ["SlotSnapshot", "pack_device_state", "unpack_device_state",
@@ -37,7 +37,8 @@ _ROW_LEAVES = frozenset(_KV_LEAVES)  # leaves with a sequence-row axis (2)
 
 
 def slot_row_capacity(cache: Dict[str, Any]) -> Optional[int]:
-    """Row capacity (window or max_len) of the cache's KV leaves.
+    """Row capacity (window or max_len) of an engine cache's KV leaves
+    (the stored layout, ``kvcache.row_capacity``).
 
     For a PAGED cache (DESIGN.md §14) the logical capacity is the block
     table's pages-per-slot times the pool's page size — the same number
@@ -51,10 +52,7 @@ def slot_row_capacity(cache: Dict[str, Any]) -> Optional[int]:
     if "block" in layers:
         pool = next(v for n, v in layers.items() if n.startswith("pool_"))
         return int(layers["block"].shape[2]) * int(pool.shape[2])
-    for name in _KV_LEAVES:
-        if name in layers:
-            return int(layers[name].shape[2])
-    return None
+    return row_capacity(layers)
 
 
 def pack_device_state(solo: Dict[str, Any], used_rows: int) -> Dict[str, Any]:

@@ -261,6 +261,16 @@ class TieredContinuousEngine(ContinuousEngine):
             raise ValueError(f"request uid={r.uid}: unknown tier {name!r} "
                              f"(engine tiers: {sorted(self.tiers)})")
 
+    @property
+    def cache(self):
+        """The default tier's arena (every KV format keeps its own in
+        ``_caches``); the programs donate the arenas they are given."""
+        return self._caches[self.tiers[self.default_tier].kv_fmt]
+
+    @cache.setter
+    def cache(self, value):
+        self._caches[self.tiers[self.default_tier].kv_fmt] = value
+
     # -- construction hooks -------------------------------------------------
 
     def _init_slot_cache(self):
@@ -303,10 +313,11 @@ class TieredContinuousEngine(ContinuousEngine):
         self._chunk_jit = self._chunks[dspec.kv_fmt]
         # reset/snapshot programs are cache-structure-polymorphic (jit
         # retraces per arena pytree), so one program each serves all tiers
-        self._reset = cached_program(
+        self._reset = self._keep_cache(cached_program(
             ("reset", cfg, mk),
             lambda: named_jit("reset_slot",
-                              functools.partial(reset_slot, cfg)))
+                              functools.partial(reset_slot, cfg),
+                              donate_argnums=0)), 0, None)
         self._snap = cached_program(
             ("snap", cfg, self._kv, mk),
             lambda: named_jit("snap", read_cache_slot))
@@ -325,10 +336,7 @@ class TieredContinuousEngine(ContinuousEngine):
             if af is None:      # shares the base engine's lane program
                 self._lane_fns[(kvf, af)] = cached_program(
                     ("lane", cfg, kvf, self.p_chunk, mk),
-                    lambda kvf=kvf: named_jit(
-                        "lane_chunk", functools.partial(
-                            self._lane_chunk_fn, cfg=cfg, kv_fmt=kvf),
-                        static_argnames=("with_head", "wrapped")))
+                    lambda kvf=kvf: self._lane_program(cfg, kvf))
             else:
                 self._lane_fns[(kvf, af)] = cached_program(
                     ("lane", cfg, kvf, self.p_chunk, mk, af),
@@ -336,12 +344,15 @@ class TieredContinuousEngine(ContinuousEngine):
                         "lane_chunk", functools.partial(
                             self._tier_lane_fn, cfg=cfg, kv_fmt=kvf,
                             act_fmt=af),
-                        static_argnames=("with_head", "wrapped")))
+                        static_argnames=("with_head", "wrapped"),
+                        donate_argnums=2))
         dspec = self.tiers[self.default_tier]
-        self._lane_fn = self._lane_fns[(dspec.kv_fmt, dspec.act_fmt)]
-        self._finish = cached_program(
+        self._lane_fn = self._keep_cache(
+            self._lane_fns[(dspec.kv_fmt, dspec.act_fmt)], 2, 1)
+        self._finish = self._keep_cache(cached_program(
             ("finish", cfg, mk),
-            lambda: named_jit("lane_finish", self._finish_prefill_fn))
+            lambda: named_jit("lane_finish", self._finish_prefill_fn,
+                              donate_argnums=3)), 3, 2)
 
     # -- jitted bodies ------------------------------------------------------
 
@@ -539,7 +550,7 @@ class TieredContinuousEngine(ContinuousEngine):
         if src != dst:
             solo = self._snap(self._caches[src], jnp.int32(slot))
             pos = int(np.asarray(jax.device_get(solo["pos"]))[0])
-            cap = slot_row_capacity(solo)
+            cap = slot_row_capacity(self._caches[src])
             used = min(pos, cap) if cap is not None else 0
             # trim+pad round trip zeroes rows beyond pos, so the
             # re-quantizer never encodes stale garbage bytes

@@ -185,8 +185,8 @@ _CACHE_DIMS = {
     # so stacked (L, ...) and VLM-grouped (G, k-1, ...) leaves both work.
     "k": (-4, -2), "v": (-4, -2),                  # (..,B,S,KVH,hd)
     "mem_k": (-4, -2), "mem_v": (-4, -2),
-    "k_packed": (-5, -3), "v_packed": (-5, -3),    # (..,B,S,KVH,nb,bpb)
-    "k_meta": (-4, -2), "v_meta": (-4, -2),        # (..,B,S,KVH,nb)
+    "k_packed": (-5, -4), "v_packed": (-5, -4),    # (..,B,KVH,Bg,D/P,S)
+    "k_meta": (-4, -3), "v_meta": (-4, -3),        # (..,B,KVH,nb,S)
     "h": (-3, -2),                                 # (..,B,di,N)
     "conv": (-3, -1),                              # (..,B,cw-1,di)
 }

@@ -252,10 +252,12 @@ def test_write_prefill_at_quantized_dense_buffer():
     out = jax.jit(lambda c, kk, vv: write_prefill_at(
         cfg, c, kk, vv, 0, 5, 3, "nxfp4"))(layer, jnp.asarray(k),
                                            jnp.asarray(v))
+    # the stored plane view keeps the context rows on the last axis
     packed = np.asarray(out["k_packed"])
-    assert packed[0, 5:8].any() and not packed[0, 8:].any()
+    assert packed[0, ..., 5:8].any() and not packed[0, ..., 8:].any()
+    assert not packed[0, ..., :5].any()
     assert not packed[1].any()                       # neighbor untouched
-    assert not np.asarray(out["k_meta"])[0, 8:].any()   # padding dropped
+    assert not np.asarray(out["k_meta"])[0, ..., 8:].any()  # padding dropped
 
 
 # ---------------------------------------------------------------------------

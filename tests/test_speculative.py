@@ -339,7 +339,9 @@ def test_kv_checksum_window_aware_on_wrapped_ring():
     name = next(n for n in ("k_packed", "k", "v_packed", "v")
                 if cache["layers"].get(n) is not None)
     leaf = cache["layers"][name]
-    s = leaf.shape[2]
+    # packed leaves are stored with the context rows on the last axis
+    plane = name.endswith("_packed")
+    s = leaf.shape[-1] if plane else leaf.shape[2]
     ptr = int(np.asarray(upto)[0]) % s
 
     base = np.asarray(kv_slot_checksum(cfg, cache, upto, hz))
@@ -347,7 +349,8 @@ def test_kv_checksum_window_aware_on_wrapped_ring():
     def flip(row):
         bad = dict(cache)
         bad["layers"] = dict(cache["layers"])
-        idx = (0, 0, row) + (0,) * (leaf.ndim - 3)
+        zeros = (0,) * (leaf.ndim - 3)
+        idx = (0, 0) + (zeros + (row,) if plane else (row,) + zeros)
         bad["layers"][name] = leaf.at[idx].set(leaf[idx] ^ 1 if
                                                leaf.dtype == jnp.uint8
                                                else leaf[idx] + 1)
